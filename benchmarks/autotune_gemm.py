@@ -54,8 +54,7 @@ def bench_shape(name: str, shape: GemmShape, *, iters: int = 3) -> None:
                           jnp.bfloat16)
 
     def run_tile(tile):
-        return kops.sr_matmul(a, b, None, sr=False, block=tile,
-                              interpret=True)
+        return kops.sr_matmul(a, b, None, sr=False, block=tile)
 
     us_d = time_fn(functools.partial(run_tile, d_cost.tile), iters=iters)
     us_t = time_fn(functools.partial(run_tile, t_cost.tile), iters=iters)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core.phases import Phase
 from repro.engine.dispatch import DEFAULT_WORD, op_key, pe_dot
 
@@ -44,6 +43,17 @@ class PEContext:
     interpret: Optional[bool] = None     # pallas interpret mode (None = auto)
     key: Optional[jax.Array] = None      # phase key for UP-phase SR entropy
     phase: Phase = Phase.FF              # program-word column this trace runs
+
+    def __post_init__(self):
+        # Mosaic kernels cannot be auto-partitioned by GSPMD; refuse here,
+        # when the step is built, rather than deep inside the compiler.
+        if (self.backend == "pallas" and self.mesh is not None
+                and self.mesh.devices.size > 1):
+            raise NotImplementedError(
+                f"kernel_backend='pallas' on a {self.mesh.devices.size}-"
+                f"device mesh: the Pallas PE kernels are not yet wrapped in "
+                f"shard_map, so they cannot be partitioned across devices; "
+                f"use kernel_backend='reference' on a mesh, or one device")
 
     # --- engine dispatch ---------------------------------------------------
 
@@ -78,14 +88,13 @@ class PEContext:
                       transpose_w=transpose_w, phase=self.phase)
 
     def shard_map(self, *, in_specs, out_specs, check_vma: bool = True):
-        """Decorator: ``shard_map`` over THIS context's mesh, through the
-        jax-version seam (``repro.compat``).  The sharded-MoE block (and
-        any future per-shard region) enters manual mode here so model
-        code never spells the jax API drift itself."""
+        """Decorator: ``jax.shard_map`` over THIS context's mesh.  The
+        sharded-MoE block (and any future per-shard region) enters manual
+        mode here, so model code never names the mesh itself."""
         if self.mesh is None:
             raise ValueError("shard_map needs a mesh-backed PEContext")
-        return _shard_map(mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
+        return jax.shard_map(mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check_vma)
 
     # --- layout constraints (the PMAG re-programming points) ---------------
 

@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ops import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -79,7 +81,7 @@ def _norm_f32(x, scale, bias, kind: str):
 def _rope_f32(x, pos, theta: float):
     """RoPE on (H, hd) at scalar position `pos`; returns x.dtype."""
     hd = x.shape[-1]
-    i2 = jax.lax.broadcasted_iota(jnp.float32, (1, hd // 2), 1)
+    i2 = jax.lax.broadcasted_iota(jnp.int32, (1, hd // 2), 1).astype(jnp.float32)
     freqs = 1.0 / (theta ** (2.0 * i2 / hd))
     ang = pos.astype(jnp.float32) * freqs              # (1, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -142,9 +144,9 @@ def _attn_unit_kernel(x_ref, n1s_ref, n1b_ref, qkvw_ref, qkvb_ref, ow_ref,
                       act: str, tn: int, with_ffn: bool):
     f32 = jnp.float32
     H, K, hd = heads, kv_heads, head_dim
-    x = x_ref[...]                                     # (1, d)
+    x = x_ref[0]                                       # (1, d)
     dt = x.dtype
-    p = pos_ref[0, 0]
+    p = pos_ref[0, 0, 0]
 
     # --- qkv projection ---
     h = _norm_f32(x, n1s_ref[...], n1b_ref[...], norm_kind)
@@ -161,22 +163,24 @@ def _attn_unit_kernel(x_ref, n1s_ref, n1b_ref, qkvw_ref, qkvb_ref, ow_ref,
     slot = p % S
     kc_out[...] = kc_in[...]
     vc_out[...] = vc_in[...]
-    kp_out[...] = kp_in[...]
     kc_out[0, pl.ds(slot, 1)] = k1.astype(kc_out.dtype).reshape(1, K, hd)
     vc_out[0, pl.ds(slot, 1)] = v1.astype(vc_out.dtype).reshape(1, K, hd)
-    kp_out[0, pl.ds(slot, 1)] = jnp.full((1,), p, jnp.int32)
+    # the position row is lane-dense: write slot `slot` by a select
+    kvp = kp_in[0]                                     # (1, S)
+    kvp = jnp.where(jax.lax.broadcasted_iota(jnp.int32, kvp.shape, 1) == slot,
+                    p, kvp)
+    kp_out[0] = kvp
 
     # --- paged attention over the arena row ---
     kc = kc_out[...][0]                                # (S, K, hd)
     vc = vc_out[...][0]
-    kvp = kp_out[...][0]                               # (S,)
     scale = 1.0 / math.sqrt(hd)
     qh = q.reshape(K, H // K, hd)
     s = jnp.einsum("kgh,skh->kgs", qh.astype(f32), kc.astype(f32)) * scale
     valid = (kvp >= 0) & (kvp <= p)
     if window is not None:
         valid &= (p - kvp) < window
-    s = jnp.where(valid[None, None, :], s, NEG_INF)
+    s = jnp.where(valid[None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     pe = jnp.exp(s - m)
     l = jnp.sum(pe, axis=-1, keepdims=True)
@@ -191,23 +195,19 @@ def _attn_unit_kernel(x_ref, n1s_ref, n1b_ref, qkvw_ref, qkvb_ref, ow_ref,
     if with_ffn:
         h2 = _norm_f32(x, n2s_ref[...], n2b_ref[...], norm_kind)
         x = _ffn_stream(x, h2, w1_ref, w2_ref, act=act, tn=tn)
-    y_ref[...] = x
+    y_ref[0] = x
 
 
 def _ffn_kernel(x_ref, n2s_ref, n2b_ref, w1_ref, w2_ref, y_ref, *,
                 norm_kind: str, act: str, tn: int):
-    x = x_ref[...]
+    x = x_ref[0]
     h2 = _norm_f32(x, n2s_ref[...], n2b_ref[...], norm_kind)
-    y_ref[...] = _ffn_stream(x, h2, w1_ref, w2_ref, act=act, tn=tn)
+    y_ref[0] = _ffn_stream(x, h2, w1_ref, w2_ref, act=act, tn=tn)
 
 
 # ---------------------------------------------------------------------------
 # Public wrappers
 # ---------------------------------------------------------------------------
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def _row2d(arr, d: int, fill: float, like) -> jax.Array:
@@ -228,9 +228,19 @@ def _whole(shape):
 
 
 def _perrow(shape):
-    """BlockSpec for a (B, ...) operand sliced one arena row per grid step."""
+    """BlockSpec for a (B, ...) operand sliced one arena row per grid step.
+
+    The block's last two dims must be the array's own (Mosaic tiles them
+    by (8, 128) unless they are whole), so a (B, n) operand enters as
+    (B, 1, n) — see :func:`_unit_row` — and never as a (1, n) block.
+    """
     nd = len(shape)
     return pl.BlockSpec((1,) + tuple(shape[1:]), lambda b: (b,) + (0,) * (nd - 1))
+
+
+def _unit_row(a):
+    """(B, n) -> (B, 1, n): a per-row operand whose row block is whole."""
+    return a.reshape(a.shape[0], 1, *a.shape[1:])
 
 
 def fused_attn_unit(x, cache_k, cache_v, cache_pos, pos, *,
@@ -248,7 +258,7 @@ def fused_attn_unit(x, cache_k, cache_v, cache_pos, pos, *,
     Returns (y (B, d), new_k, new_v, new_pos).  with_ffn=False skips the
     FF block (MoE units keep their experts per-op).
     """
-    interp = _interpret_default() if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     B, d = x.shape
     S, K, hd = cache_k.shape[1:]
     qn = qkv_w.shape[1]
@@ -269,31 +279,34 @@ def fused_attn_unit(x, cache_k, cache_v, cache_pos, pos, *,
         _attn_unit_kernel, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
         rope_theta=rope_theta, window=window, norm_kind=norm_kind, act=act,
         tn=tn, with_ffn=with_ffn)
-    return pl.pallas_call(
+    x3, kp3 = _unit_row(x), _unit_row(cache_pos)
+    pos3 = pos.astype(jnp.int32).reshape(B, 1, 1)
+    y, kc, vc, kp = pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=[
-            _perrow(x.shape),
+            _perrow(x3.shape),
             _whole(n1s.shape), _whole(n1b.shape),
             _whole(qkv_w.shape), _whole(qb.shape), _whole(o_w.shape),
             _whole(n2s.shape), _whole(n2b.shape),
             _whole(w_in.shape), _whole(w_out.shape),
             _perrow(cache_k.shape), _perrow(cache_v.shape),
-            _perrow(cache_pos.shape), _perrow((B, 1)),
+            _perrow(kp3.shape), _perrow(pos3.shape),
         ],
         out_specs=[
-            _perrow(x.shape), _perrow(cache_k.shape),
-            _perrow(cache_v.shape), _perrow(cache_pos.shape),
+            _perrow(x3.shape), _perrow(cache_k.shape),
+            _perrow(cache_v.shape), _perrow(kp3.shape),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, d), x.dtype),
+            jax.ShapeDtypeStruct(x3.shape, x.dtype),
             jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
             jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
-            jax.ShapeDtypeStruct(cache_pos.shape, cache_pos.dtype),
+            jax.ShapeDtypeStruct(kp3.shape, cache_pos.dtype),
         ],
         interpret=interp,
-    )(x, n1s, n1b, qkv_w, qb, o_w, n2s, n2b, w_in, w_out,
-      cache_k, cache_v, cache_pos, pos.astype(jnp.int32).reshape(B, 1))
+    )(x3, n1s, n1b, qkv_w, qb, o_w, n2s, n2b, w_in, w_out,
+      cache_k, cache_v, kp3, pos3)
+    return y.reshape(B, d), kc, vc, kp.reshape(cache_pos.shape)
 
 
 def fused_ffn(x, *, norm2_scale, norm2_bias, w_in, w_out,
@@ -304,7 +317,7 @@ def fused_ffn(x, *, norm2_scale, norm2_bias, w_in, w_out,
     x: (B, d) -> (B, d).  SSM recurrences are VPU words (never lowered
     onto the MAC array), so their units fuse only the FF half.
     """
-    interp = _interpret_default() if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     B, d = x.shape
     f = w_out.shape[0]
     tn = _clip_block_n(block_n, f)
@@ -312,15 +325,17 @@ def fused_ffn(x, *, norm2_scale, norm2_bias, w_in, w_out,
     n2b = _row2d(norm2_bias, d, 0.0, jnp.float32)
     kernel = functools.partial(_ffn_kernel, norm_kind=norm_kind, act=act,
                                tn=tn)
-    return pl.pallas_call(
+    x3 = _unit_row(x)
+    y = pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=[
-            _perrow(x.shape),
+            _perrow(x3.shape),
             _whole(n2s.shape), _whole(n2b.shape),
             _whole(w_in.shape), _whole(w_out.shape),
         ],
-        out_specs=_perrow(x.shape),
-        out_shape=jax.ShapeDtypeStruct((B, d), x.dtype),
+        out_specs=_perrow(x3.shape),
+        out_shape=jax.ShapeDtypeStruct(x3.shape, x.dtype),
         interpret=interp,
-    )(x, n2s, n2b, w_in, w_out)
+    )(x3, n2s, n2b, w_in, w_out)
+    return y.reshape(B, d)

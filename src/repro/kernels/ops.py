@@ -18,7 +18,12 @@ from repro.kernels import sr_round as _rr
 from repro.kernels import wkv6 as _wkv
 
 
-def _interpret_default() -> bool:
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one interpret-mode choice for every kernel: an explicit value
+    wins; otherwise the kernels run in the interpreter on the CPU backend
+    only, and compile on every other backend."""
+    if interpret is not None:
+        return interpret
     return jax.default_backend() == "cpu"
 
 
@@ -44,7 +49,7 @@ def make_rbits(key: jax.Array, shape: tuple, *, lo: bool = False,
 def sr_round(x: jax.Array, key: jax.Array, *, lo: bool = False,
              interpret: bool | None = None) -> jax.Array:
     """Stochastically round f32 (M, N) to bf16."""
-    interp = _interpret_default() if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     rbits = make_rbits(key, x.shape, lo=lo)
     return _rr.sr_round(x, rbits, interpret=interp)
 
@@ -58,7 +63,7 @@ def sr_matmul(a: jax.Array, b: jax.Array, key: jax.Array | None = None, *,
     """bf16 matmul, f32 accumulation, optional fused SR-bf16 writeback.
 
     trans_b computes a @ b.T via the counter-swept BlockSpec (BP's W^T)."""
-    interp = _interpret_default() if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     rbits = None
     n = b.shape[0] if trans_b else b.shape[1]
     if sr:
@@ -75,7 +80,7 @@ def outer_accum(x: jax.Array, dy: jax.Array, key: jax.Array | None = None, *,
                 block: tuple = (256, 256, 512),
                 interpret: bool | None = None) -> jax.Array:
     """FC-UP: dW = scale * X^T dY (fused minibatch average + SR)."""
-    interp = _interpret_default() if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     rbits = None
     if sr:
         assert key is not None
@@ -89,7 +94,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
          u: jax.Array, *, chunk: int = 64,
          interpret: bool | None = None):
     """Chunked WKV6.  Model-facing layout (B, S, H, hd) + u (H, hd)."""
-    interp = _interpret_default() if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     B, S, H, hd = r.shape
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     uu = jnp.tile(u, (B, 1))

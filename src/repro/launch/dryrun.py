@@ -23,6 +23,7 @@ from repro.configs import (ASSIGNED_ARCHS, SHAPES, get_config,   # noqa: E402
                            shape_applicable)
 from repro.configs.base import TrainConfig                       # noqa: E402
 from repro.core import compile_program                           # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache      # noqa: E402
 from repro.launch.mesh import make_production_mesh, mesh_spec_for  # noqa: E402
 from repro.runtime import train_loop as tl                       # noqa: E402
 from repro.runtime.inputs import input_specs, key_spec           # noqa: E402
@@ -237,8 +238,7 @@ def main():
                          "bubble fraction for this many stages per cell")
     args = ap.parse_args()
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
+    enable_compile_cache()
 
     archs = ASSIGNED_ARCHS if args.arch == "all" else args.arch.split(",")
     shapes = list(SHAPES) if args.shape == "all" else args.shape.split(",")

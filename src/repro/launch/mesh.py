@@ -8,20 +8,31 @@ the memory modules out as the OUTERMOST mesh axis, so a collective that
 stays inside the inner axes never leaves a module — which is exactly the
 property the planner's hop-class cost model prices
 (`core.dataflow.ModuleTopology`).
+
+Every mesh is built by :func:`auto_mesh`: the program places arrays with
+``with_sharding_constraint``, which only accepts ``Auto`` mesh axes
+(``jax.make_mesh`` defaults to ``Explicit`` ones).
 """
 from __future__ import annotations
 
 import warnings
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.dataflow import MeshSpec, ModuleTopology
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagation)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def mesh_spec_for(mesh, *, topology: ModuleTopology | None = None) -> MeshSpec:
@@ -58,7 +69,7 @@ def make_host_mesh(n_devices: int | None = None, *, data: int | None = None,
     if data is None or model is None:
         model = 1
         data = n
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def make_module_mesh(topology: ModuleTopology, *, model: int = 1,
@@ -83,7 +94,7 @@ def make_module_mesh(topology: ModuleTopology, *, model: int = 1,
             f"needs {topology.n_modules}x{topology.pes_per_module}="
             f"{topology.n_pes}; no module mesh", stacklevel=2)
         return None
-    return jax.make_mesh(
+    return auto_mesh(
         (topology.n_modules, topology.pes_per_module // model, model),
         (topology.module_axis, "data", "model"))
 
@@ -127,8 +138,8 @@ def make_pipeline_mesh(num_stages: int, n_devices: int | None = None):
             f"{num_stages} stages; falling back to virtual stages",
             stacklevel=2)
         return None
-    return jax.make_mesh((num_stages, n // num_stages, 1),
-                         ("stage", "data", "model"))
+    return auto_mesh((num_stages, n // num_stages, 1),
+                     ("stage", "data", "model"))
 
 
 def pipeline_mesh_spec(num_stages: int, base: MeshSpec | None = None) -> MeshSpec:

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_reduced
 from repro.configs.base import ShapeConfig
 from repro.core import compile_program
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, mesh_spec_for
 from repro.models import encdec
 from repro.models import transformer as tfm
@@ -177,7 +178,7 @@ def run_fleet(args, cfg):
     return 0
 
 
-def run_engine(args, cfg, mesh, use_mesh):
+def run_engine(args, cfg, mesh, use_mesh, on_done=None):
     """Continuous batching: slot arena + chunked prefill + masked decode."""
     lo, hi = (int(x) for x in args.prompt_lens.split(","))
     max_len = args.max_len or hi + args.gen
@@ -211,10 +212,15 @@ def run_engine(args, cfg, mesh, use_mesh):
               f"({engine.spec_stats['accepted']/v:.2f} accepted/verify)")
     first = trace[0].rid
     print(f"sample ({first}):", results[first][:16])
+    if on_done is not None:
+        on_done(trace, results)
     return 0
 
 
-def main(argv=None):
+def main(argv=None, *, on_done=None):
+    """CLI entry.  In engine mode an in-process caller may also pass
+    on_done(trace, results): the requests served and {rid: generated
+    token ids}."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -281,6 +287,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=None,
                     help="[single-shot] uniform prompt length (default 32)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     fleet_mode = (args.replicas > 1 or args.prefix_cache
@@ -303,7 +310,7 @@ def main(argv=None):
         if args.speculative:
             ap.error("--speculative applies to single-engine mode only")
         return run_fleet(args, cfg)
-    return run_engine(args, cfg, mesh, use_mesh)
+    return run_engine(args, cfg, mesh, use_mesh, on_done=on_done)
 
 
 if __name__ == "__main__":

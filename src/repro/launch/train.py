@@ -11,6 +11,8 @@ straggler detection, stateless-by-step data pipeline).
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -21,14 +23,19 @@ from repro.configs import SHAPES, get_config, get_reduced
 from repro.configs.base import ShapeConfig, TrainConfig
 from repro.core import ModuleTopology, compile_program
 from repro.core.dataflow import ICI_BW
-from repro.data import SyntheticLM
+from repro.data import PipelineConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (make_host_mesh, make_module_mesh,
                                mesh_spec_for, module_mesh_spec)
 from repro.runtime import train_loop as tl
 from repro.runtime.fault_tolerance import run_with_recovery
 
 
-def main(argv=None):
+def main(argv=None, *, max_failures: int = 3, on_metrics=None, on_done=None):
+    """CLI entry.  In-process callers may also pass max_failures (step
+    failures tolerated, see ``run_with_recovery``), on_metrics(step,
+    metrics, seconds) called after every step, and on_done(state) called
+    with the final train state."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--reduced", action="store_true",
@@ -72,12 +79,14 @@ def main(argv=None):
     ap.add_argument("--hbm-budget-gb", type=float, default=None,
                     help="per-module HBM budget for --auto-memory "
                          "(default: 90%% of the v5e 16GB)")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_train"))
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.shape:
@@ -145,6 +154,7 @@ def main(argv=None):
                             checkpoint_dir=args.ckpt_dir,
                             checkpoint_every=args.ckpt_every)
 
+    use_mesh = mesh if mesh.devices.size > 1 else None
     if args.pipeline_stages > 1:
         from repro.core.program import compile_stage_programs
         from repro.launch.mesh import make_pipeline_mesh, pipeline_mesh_spec
@@ -193,9 +203,7 @@ def main(argv=None):
               f"{'ppermute mesh' if pmesh else 'virtual stages'}, "
               f"bubble={sched.bubble_fraction():.1%}")
     else:
-        use_mesh = mesh if mesh.devices.size > 1 else None
         step_fn, opt = tl.make_train_step(cfg, program, train_cfg, use_mesh)
-    jstep = jax.jit(step_fn, donate_argnums=(0,))
     state = tl.init_state(cfg, program, train_cfg, jax.random.PRNGKey(args.seed), opt)
 
     ckpt = Checkpointer(args.ckpt_dir)
@@ -205,23 +213,39 @@ def main(argv=None):
         state = jax.tree.map(jnp.asarray, host)
         print(f"resumed from step {step}")
 
-    pipe = SyntheticLM(cfg, shape)
+    if use_mesh is not None and args.pipeline_stages <= 1:
+        # place the state (params by the program, moments ZeRO-1) and the
+        # batch on the mesh, and keep that layout from step to step
+        sspecs = tl.named(mesh, tl.state_shardings(cfg, program, train_cfg,
+                                                   mesh, opt))
+        bspecs = tl.named(mesh, tl.batch_pspecs(cfg, shape, program))
+        jstep = jax.jit(step_fn, in_shardings=(sspecs, bspecs, None),
+                        out_shardings=(sspecs, None), donate_argnums=(0,))
+        state = jax.device_put(state, sspecs)
+    else:
+        jstep = jax.jit(step_fn, donate_argnums=(0,))
+
+    pipe = SyntheticLM(cfg, shape, PipelineConfig(seed=args.seed))
     losses = []
 
-    def on_metrics(step, metrics, dt):
+    def log_metrics(step, metrics, dt):
         losses.append(float(metrics["loss"]))
         if step % args.log_every == 0:
             print(f"step {step:5d} loss={metrics['loss']:.4f} "
                   f"gnorm={metrics['grad_norm']:.3f} {dt*1e3:.0f}ms",
                   flush=True)
+        if on_metrics is not None:
+            on_metrics(step, metrics, dt)
 
     state = run_with_recovery(
         step_fn=jstep, state=state, batches=pipe.batch_at, ckpt=ckpt,
         meta=meta, n_steps=args.steps,
-        checkpoint_every=args.ckpt_every,
-        key=jax.random.key(args.seed), on_metrics=on_metrics)
+        checkpoint_every=args.ckpt_every, max_failures=max_failures,
+        key=jax.random.key(args.seed), on_metrics=log_metrics)
     print(f"done: {args.steps} steps; loss {losses[0]:.4f} -> "
           f"{np.mean(losses[-10:]):.4f}")
+    if on_done is not None:
+        on_done(state)
     return 0
 
 

@@ -54,10 +54,11 @@ MESHES = {
 }
 
 
-def make_measure(interpret: bool = True):
+def make_measure(interpret: bool | None = None):
     """tile -> seconds on THIS host: times the real sr_matmul at a probe
     shape capped to the tile (full problem sizes are minutes in interpret
-    mode; relative tile cost is what the refinement needs)."""
+    mode; relative tile cost is what the refinement needs).  interpret=None
+    compiles the kernel on an accelerator and interprets it on the CPU."""
     import jax
     import jax.numpy as jnp
 

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.phases import Phase
 from repro.engine import PEContext
@@ -68,7 +67,7 @@ def _ppermute_shift(tree, mesh, direction: int):
     S = mesh.shape["stage"]
     perm = [(i, i + direction) for i in range(S) if 0 <= i + direction < S]
 
-    @functools.partial(_shard_map, mesh=mesh, in_specs=P("stage"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("stage"),
                        out_specs=P("stage"))
     def shift(t):
         return jax.tree.map(

@@ -25,6 +25,7 @@ What is implemented and TESTED here (CPU container, scaled down honestly):
 """
 from __future__ import annotations
 
+import sys
 import time
 from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Optional
@@ -108,7 +109,8 @@ def run_with_recovery(*, step_fn: Callable, state: Any, batches: Callable,
     batches: step -> batch (pure).  fail_injector: step -> None or raise
     (test hook).  Restores from the latest checkpoint on failure and
     replays from the stored step — restart-exact because batches(step) is
-    stateless.
+    stateless.  Every caught failure is printed to stderr with its step;
+    max_failures=0 re-raises the first one.
     """
     key = key if key is not None else jax.random.PRNGKey(0)
     timer = StepTimer()
@@ -129,15 +131,20 @@ def run_with_recovery(*, step_fn: Callable, state: Any, batches: Callable,
             step += 1
             if step % checkpoint_every == 0:
                 ckpt.save(step, state, meta)
-        except Exception:
+        except Exception as e:
             failures += 1
+            print(f"run_with_recovery: step {step} failed "
+                  f"({failures}/{max_failures} tolerated): "
+                  f"{type(e).__name__}: {e}", file=sys.stderr, flush=True)
             if failures > max_failures:
                 raise
+            ckpt.wait()                    # a save may still be writing
             latest = ckpt.latest_step()
             if latest is None:
                 raise
-            host_state, step, _ = ckpt.restore(
-                jax.tree.map(np.asarray, jax.device_get(state)))
+            # the template only names the tree's paths: a step that donated
+            # `state` may have deleted its buffers, so never read them
+            host_state, step, _ = ckpt.restore(jax.tree.map(lambda _: 0, state))
             state = jax.tree.map(jax.numpy.asarray, host_state)
             step = int(step)
     ckpt.save(n_steps, state, meta, blocking=True)

@@ -21,3 +21,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
+# no persistent compilation cache in the test run: nothing is written to
+# the checkout's .jax_cache (or to JAX_COMPILATION_CACHE_DIR)
+jax.config.update("jax_enable_compilation_cache", False)

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint import Checkpointer
 from repro.configs import get_reduced
@@ -94,3 +95,50 @@ def test_straggler_detection():
         t.record(i, 0.10 + 0.001 * (i % 3))
     assert t.record(20, 0.5) is True      # 5x median = straggler
     assert t.stragglers and t.stragglers[0][0] == 20
+
+
+def test_recovery_reports_failure_and_restores_donated_state(tmp_path,
+                                                             capsys):
+    """A step that fails after its state was donated still recovers (the
+    restore never reads the donated buffers), and the failure is reported
+    on stderr with its step."""
+    cfg = get_reduced("qwen2-0.5b")
+    program = compile_program(cfg, SMOKE, MESH1, precision="fp32")
+    tc = TrainConfig(optimizer="sgdm", lr=1e-2, precision="fp32")
+    step_fn, opt = tl.make_train_step(cfg, program, tc, mesh=None)
+    donating = jax.jit(step_fn, donate_argnums=(0,))
+    state = tl.init_state(cfg, program, tc, jax.random.PRNGKey(0), opt)
+    pipe = SyntheticLM(cfg, SMOKE)
+    boom = {"armed": True}
+
+    def step(state, batch, key):
+        out = donating(state, batch, key)          # `state` is gone now
+        if int(jax.device_get(out[0]["step"])) == 3 and boom["armed"]:
+            boom["armed"] = False
+            raise RuntimeError("synthetic failure after the step ran")
+        return out
+
+    ck = Checkpointer(str(tmp_path))
+    final = run_with_recovery(step_fn=step, state=state,
+                              batches=pipe.batch_at, ckpt=ck, meta={},
+                              n_steps=4, checkpoint_every=2)
+    assert int(jax.device_get(final["step"])) == 4
+    err = capsys.readouterr().err
+    assert "step 2 failed" in err and "synthetic failure" in err
+
+
+def test_recovery_with_no_tolerance_raises_the_first_failure(tmp_path,
+                                                              capsys):
+    _, _, step_fn, state, pipe = _setup(tmp_path)
+    ck = Checkpointer(str(tmp_path))
+    ck.save(0, state, blocking=True)
+
+    def injector(step):
+        if step == 1:
+            raise RuntimeError("synthetic node failure")
+
+    with pytest.raises(RuntimeError, match="synthetic node failure"):
+        run_with_recovery(step_fn=step_fn, state=state,
+                          batches=pipe.batch_at, ckpt=ck, meta={},
+                          n_steps=3, max_failures=0, fail_injector=injector)
+    assert "step 1 failed (1/0 tolerated)" in capsys.readouterr().err

@@ -20,10 +20,10 @@ from repro.configs import get_reduced
 from repro.configs.base import ShapeConfig, TrainConfig
 from repro.core import compile_program
 from repro.data import SyntheticLM
-from repro.launch.mesh import mesh_spec_for
+from repro.launch.mesh import auto_mesh, mesh_spec_for
 from repro.runtime import train_loop as tl
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = auto_mesh((4, 2), ("data", "model"))
 cfg = get_reduced("qwen2-0.5b")
 shape = ShapeConfig("dist", seq_len=32, global_batch=8, kind="train")
 program = compile_program(cfg, shape, mesh_spec_for(mesh))

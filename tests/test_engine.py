@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_reduced
-from repro.configs.base import ShapeConfig
+from repro.configs.base import ShapeConfig, TrainConfig
 from repro.core import MeshSpec, PEWord, compile_program
 from repro.engine import PEContext, op_key, pe_dot, up_key
 from repro.kernels import ops as kops
@@ -257,3 +257,33 @@ def test_up_entropy_decorrelated_across_scan_iterations():
 
     dws = jax.grad(loss, argnums=1)(x, w)
     assert bool(jnp.all(jnp.isfinite(dws.astype(jnp.float32))))
+
+
+# ---------------------------------------------------------------------------
+# Pallas on a mesh: refused when the step is built
+# ---------------------------------------------------------------------------
+
+
+class _FourDeviceMesh:
+    """Stand-in for a 4-device mesh (the test process has one device);
+    the refusal reads only the device count."""
+    devices = np.empty((4, 1), object)
+
+
+@pytest.mark.parametrize("build", [
+    lambda cfg, prog, mesh: tl.make_train_step(
+        cfg, prog, TrainConfig(kernel_backend="pallas"), mesh),
+    lambda cfg, prog, mesh: tl.make_decode_step(
+        cfg, prog, mesh, kernel_backend="pallas"),
+    lambda cfg, prog, mesh: tl.make_chunk_step(
+        cfg, prog, mesh, kernel_backend="pallas"),
+], ids=["train", "decode", "chunk"])
+def test_pallas_on_a_multi_device_mesh_is_refused_at_build(build):
+    cfg = get_reduced("qwen2-0.5b")
+    shape = ShapeConfig("t", seq_len=16, global_batch=4, kind="train")
+    prog = compile_program(cfg, shape, MESH1)
+    with pytest.raises(NotImplementedError, match="not yet wrapped in shard_map"):
+        build(cfg, prog, _FourDeviceMesh())
+    # one device, or the reference backend, builds as before
+    build(cfg, prog, None)
+    PEContext(_FourDeviceMesh(), prog, backend="reference")

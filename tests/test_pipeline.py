@@ -351,13 +351,13 @@ from repro.configs.base import ShapeConfig, TrainConfig
 from repro.core import MeshSpec, compile_program
 from repro.core.program import compile_stage_programs
 from repro.data import SyntheticLM
-from repro.launch.mesh import pipeline_mesh_spec
+from repro.launch.mesh import auto_mesh, pipeline_mesh_spec
 from repro.pipeline import make_pipeline_train_step, partition_model
 from repro.runtime import train_loop as tl
 
 cfg = get_reduced("olmo-1b")
 shape = ShapeConfig("pp", seq_len=16, global_batch=4, kind="train")
-mesh = jax.make_mesh((2, 1, 1), ("stage", "data", "model"))
+mesh = auto_mesh((2, 1, 1), ("stage", "data", "model"))
 sspec = pipeline_mesh_spec(2)
 assert sspec.pp == 2
 tc = TrainConfig(optimizer="adamw", lr=2e-3, microbatch=2)

@@ -69,3 +69,20 @@ def test_serving_cache_consistency():
     assert close.mean() > 0.98, close.mean()
     assert (np.argmax(np.asarray(logits[:, 0]), -1)
             == np.argmax(np.asarray(full[:, -1]), -1)).all()
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache sits
+    at the checkout's fixed .jax_cache (found from the package path)."""
+    import os
+
+    from repro.launch import compile_cache as cc
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert cc.enable_compile_cache() == "/elsewhere" and calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = cc.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", path)]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")

@@ -16,8 +16,9 @@ from repro.core import (HOP_INTER, HOP_INTRA, MeshSpec, ModuleTopology,
                         compile_program, extract_ops, plan_model,
                         split_hop_bytes)
 from repro.core.dataflow import ICI_BW
-from repro.launch.mesh import (make_module_mesh, make_pipeline_mesh,
-                               mesh_spec_for, module_mesh_spec)
+from repro.launch.mesh import (auto_mesh, make_module_mesh,
+                               make_pipeline_mesh, mesh_spec_for,
+                               module_mesh_spec)
 from repro.pipeline.partition import (partition_model, place_stages,
                                       stage_edges)
 from repro.runtime.fault_tolerance import elastic_replan, surviving_topology
@@ -210,20 +211,20 @@ def test_topology_validation():
 
 
 def test_mesh_spec_for_derives_axes_from_mesh():
-    spec = mesh_spec_for(jax.make_mesh((1, 1), ("replica", "tensor")))
+    spec = mesh_spec_for(auto_mesh((1, 1), ("replica", "tensor")))
     assert spec.tp_axis == "tensor"          # no "model": innermost wins
     assert spec.batch_axes == ("replica",)
-    spec = mesh_spec_for(jax.make_mesh((1, 1, 1), ("pod", "data", "model")))
+    spec = mesh_spec_for(auto_mesh((1, 1, 1), ("pod", "data", "model")))
     assert spec.tp_axis == "model"
     assert spec.batch_axes == ("pod", "data")
     # stage slices layers, never batch
-    spec = mesh_spec_for(jax.make_mesh((1, 1, 1), ("stage", "data", "model")))
+    spec = mesh_spec_for(auto_mesh((1, 1, 1), ("stage", "data", "model")))
     assert spec.batch_axes == ("data",)
 
 
 def test_mesh_spec_for_threads_topology():
     topo = ModuleTopology(n_modules=1, pes_per_module=1)
-    mesh = jax.make_mesh((1, 1, 1), ("module", "data", "model"))
+    mesh = auto_mesh((1, 1, 1), ("module", "data", "model"))
     spec = mesh_spec_for(mesh, topology=topo)
     assert spec.topology is topo
     assert spec.batch_axes == ("module", "data")
@@ -371,7 +372,7 @@ def test_module_loss_replan_parity(tmp_path):
     host_state, step, _ = ck.restore(jax.device_get(state))
     assert step == 2
     survivor = surviving_topology(topo2, 1)
-    new_mesh = jax.make_mesh((1, 1), ("data", "model"))
+    new_mesh = auto_mesh((1, 1), ("data", "model"))
     prog1, step1, state1, _ = elastic_replan(
         cfg, SMOKE, new_mesh, host_state, tc, "fp32", topology=survivor)
     step1 = jax.jit(step1)
@@ -399,3 +400,14 @@ def test_module_loss_replan_parity(tmp_path):
                     jax.tree.leaves(state_ref["params"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-7)
+
+
+def test_every_mesh_has_auto_axes():
+    """with_sharding_constraint only accepts Auto axes; jax.make_mesh
+    defaults to Explicit ones."""
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    for mesh in (auto_mesh((1, 1), ("data", "model")), make_host_mesh(),
+                 make_module_mesh(ModuleTopology(n_modules=1,
+                                                 pes_per_module=1))):
+        assert set(mesh.axis_types) == {AxisType.Auto}
