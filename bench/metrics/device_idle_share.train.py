@@ -1,0 +1,9 @@
+"""Share of the traced training window in which no operation ran on the
+device, in %: 1 - (union of device op intervals) / window."""
+
+
+def read(rec):
+    t = rec.get("trace")
+    if rec["kind"] != "train" or not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

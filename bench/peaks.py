@@ -1,0 +1,25 @@
+"""Published peaks of each accelerator the benchmark runs on, keyed by the
+``device_kind`` that JAX reports.  A kind missing here is an error: a
+roofline or utilization share against a guessed peak means nothing.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+    # 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
