@@ -120,11 +120,10 @@ def bench_pred(arch: str, *, requests: int, prompt_lens: tuple, gen: int,
     eng.run(poisson_trace(requests, vocab_size=cfg.vocab_size,
                           prompt_lens=prompt_lens, gen_tokens=gen,
                           mean_interarrival_steps=1.0, seed=seed))
-    v = max(1, eng.spec_stats["verifies"])
+    c = eng.counters
     row(f"serve_pred/{arch}/spec{tag}", step_s * 1e6,
-        f"pred_accept_per_verify={eng.spec_stats['accepted'] / v:.3f} "
-        f"verifies={eng.spec_stats['verifies']} "
-        f"accepted={eng.spec_stats['accepted']} k={spec_k}")
+        f"pred_accept_per_verify={c['accepted'] / max(1, c['verifies']):.3f} "
+        f"verifies={c['verifies']} accepted={c['accepted']} k={spec_k}")
 
 
 def run(smoke: bool = True) -> None:

@@ -30,6 +30,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs import get_config, get_reduced
 from repro.configs.base import ShapeConfig
@@ -204,12 +205,19 @@ def run_engine(args, cfg, mesh, use_mesh, on_done=None):
           f"{(n_prompt+stats['tokens'])/wall:.1f} tok/s (total); "
           f"per-token latency p50={stats['p50_ms']:.1f}ms "
           f"p99={stats['p99_ms']:.1f}ms")
+    c = engine.counters
+    done = engine.sched.finished.values()
+    wait = [st.t_admit - st.t_submit for st in done]
+    to_first = [st.t_first - st.t_admit for st in done]
+    print(f"queue wait p95={np.percentile(wait, 95) * 1e3:.1f}ms; "
+          f"admit to first token p95={np.percentile(to_first, 95) * 1e3:.1f}ms; "
+          f"forced rows {c['forced_rows']}/{c['decode_rows']} decode rows "
+          f"({c['forced_rows'] / max(1, c['decode_rows']):.1%})")
     if args.speculative:
-        v = max(1, engine.spec_stats["verifies"])
+        v = max(1, c["verifies"])
         print(f"speculative k={args.speculative}: "
-              f"verifies={engine.spec_stats['verifies']} "
-              f"accepted={engine.spec_stats['accepted']} "
-              f"({engine.spec_stats['accepted']/v:.2f} accepted/verify)")
+              f"verifies={c['verifies']} accepted={c['accepted']} "
+              f"({c['accepted'] / v:.2f} accepted/verify)")
     first = trace[0].rid
     print(f"sample ({first}):", results[first][:16])
     if on_done is not None:

@@ -6,7 +6,7 @@
 Runs on whatever devices exist (CPU smoke -> TPU pod): builds the dataflow
 program for the real mesh, jits the train step with the program's
 shardings, and drives the fault-tolerant loop (checkpoint/restart,
-straggler detection, stateless-by-step data pipeline).
+per-step profiler spans, stateless-by-step data pipeline).
 """
 from __future__ import annotations
 

@@ -1,16 +1,16 @@
-"""Fault tolerance: restart-exact recovery, elastic re-mesh, stragglers.
+"""Fault tolerance: restart-exact recovery and elastic re-mesh.
 
-At 1000+ nodes the failure model is: (a) hard node loss -> restart from the
-latest checkpoint, possibly on a smaller mesh; (b) stragglers -> detect via
-step-time outliers, mitigate with synchronous-with-spares or by excluding
-the slow host at the next restart boundary.
+At 1000+ nodes the failure model is hard node loss -> restart from the
+latest checkpoint, possibly on a smaller mesh.
 
 What is implemented and TESTED here (CPU container, scaled down honestly):
 
   * ``run_with_recovery`` — the driver loop: catches step failures,
     restores the latest checkpoint, optionally re-plans the dataflow
     program for a new mesh (elastic), and resumes bit-exactly (the data
-    pipeline is stateless-by-step).
+    pipeline is stateless-by-step).  Each iteration is a profiler step
+    span ``train_loop.step`` (``step_num``), so a trace pins a slow step
+    to its number and to the phase it spent the time in.
   * ``elastic_replan`` — recompile the dataflow program for a surviving
     mesh and re-place the host-state under the new shardings.  Because the
     planner (core/dataflow.py) is a pure function of (ops, mesh), the SAME
@@ -20,42 +20,19 @@ What is implemented and TESTED here (CPU container, scaled down honestly):
     the :class:`~repro.core.dataflow.ModuleTopology` by the dead modules
     and ``elastic_replan(topology=...)`` re-plans with the survivor's
     hop-class costs while the checkpoint reshards onto the smaller mesh.
-  * ``StepTimer`` — straggler detection by robust z-score on step times;
-    in production the hook triggers spare promotion, here it records.
 """
 from __future__ import annotations
 
 import sys
 import time
-from dataclasses import dataclass, field, replace
+from dataclasses import replace
 from typing import Any, Callable, Optional
 
 import jax
-import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import Checkpointer, replace_on_mesh
 from repro.core.dataflow import ModuleTopology
-
-
-@dataclass
-class StepTimer:
-    window: int = 50
-    threshold: float = 3.0          # robust z-score
-    times: list = field(default_factory=list)
-    stragglers: list = field(default_factory=list)
-
-    def record(self, step: int, dt: float) -> bool:
-        self.times.append(dt)
-        hist = self.times[-self.window:]
-        if len(hist) < 10:
-            return False
-        med = float(np.median(hist))
-        mad = float(np.median(np.abs(np.array(hist) - med))) + 1e-9
-        z = (dt - med) / (1.4826 * mad)
-        if z > self.threshold:
-            self.stragglers.append((step, dt, z))
-            return True
-        return False
 
 
 def surviving_topology(topology: ModuleTopology,
@@ -113,24 +90,28 @@ def run_with_recovery(*, step_fn: Callable, state: Any, batches: Callable,
     max_failures=0 re-raises the first one.
     """
     key = key if key is not None else jax.random.PRNGKey(0)
-    timer = StepTimer()
     failures = 0
     step = int(jax.device_get(state["step"]))
     while step < n_steps:
         try:
-            if fail_injector is not None:
-                fail_injector(step)
-            t0 = time.monotonic()
-            state, metrics = step_fn(state, batches(step),
-                                     jax.random.fold_in(key, step))
-            metrics = jax.device_get(metrics)
-            dt = time.monotonic() - t0
-            timer.record(step, dt)
-            if on_metrics is not None:
-                on_metrics(step, metrics, dt)
-            step += 1
-            if step % checkpoint_every == 0:
-                ckpt.save(step, state, meta)
+            with StepTraceAnnotation("train_loop.step", step_num=step):
+                if fail_injector is not None:
+                    fail_injector(step)
+                t0 = time.monotonic()
+                with TraceAnnotation("train_loop.batch"):
+                    batch = batches(step)
+                    step_key = jax.random.fold_in(key, step)
+                with TraceAnnotation("train_loop.dispatch"):
+                    state, metrics = step_fn(state, batch, step_key)
+                with TraceAnnotation("train_loop.wait"):
+                    metrics = jax.device_get(metrics)
+                dt = time.monotonic() - t0
+                if on_metrics is not None:
+                    on_metrics(step, metrics, dt)
+                step += 1
+                if step % checkpoint_every == 0:
+                    with TraceAnnotation("train_loop.checkpoint"):
+                        ckpt.save(step, state, meta)
         except Exception as e:
             failures += 1
             print(f"run_with_recovery: step {step} failed "
@@ -138,14 +119,17 @@ def run_with_recovery(*, step_fn: Callable, state: Any, batches: Callable,
                   f"{type(e).__name__}: {e}", file=sys.stderr, flush=True)
             if failures > max_failures:
                 raise
-            ckpt.wait()                    # a save may still be writing
-            latest = ckpt.latest_step()
-            if latest is None:
-                raise
-            # the template only names the tree's paths: a step that donated
-            # `state` may have deleted its buffers, so never read them
-            host_state, step, _ = ckpt.restore(jax.tree.map(lambda _: 0, state))
-            state = jax.tree.map(jax.numpy.asarray, host_state)
-            step = int(step)
+            with TraceAnnotation("train_loop.restore"):
+                ckpt.wait()                # a save may still be writing
+                latest = ckpt.latest_step()
+                if latest is None:
+                    raise
+                # the template only names the tree's paths: a step that
+                # donated `state` may have deleted its buffers, so never
+                # read them
+                host_state, step, _ = ckpt.restore(
+                    jax.tree.map(lambda _: 0, state))
+                state = jax.tree.map(jax.numpy.asarray, host_state)
+                step = int(step)
     ckpt.save(n_steps, state, meta, blocking=True)
     return state

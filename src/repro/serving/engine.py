@@ -41,11 +41,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.program import Program
 from repro.runtime import train_loop as tl
-from repro.serving.scheduler import DECODE, Request, Scheduler
+from repro.serving.scheduler import DECODE, PREFILL, Request, Scheduler
 from repro.serving.slots import (SlotPool, plan_cache_arena, reset_slots,
                                  slot_bytes)
 
@@ -111,6 +112,13 @@ class ServingEngine:
         self.cache = tl.model_module(cfg).init_cache(cfg, n_slots, max_len)
         self.step_count = 0
         self.events: list = []
+        # always-on work counts (Python ints; docs/ARCHITECTURE.md):
+        # decode_rows sums the masked decode's active rows, forced_rows
+        # the PREFILL-phase (teacher-forced prompt-tail) part of them
+        self.counters = dict.fromkeys(
+            ("steps", "chunk_steps", "decode_steps", "decode_rows",
+             "forced_rows", "admitted", "evicted", "verifies", "accepted"),
+            0)
         # fleet seams (serving/fleet.py): admit_hook(engine, state) runs
         # after a newly admitted request's arena row is reset (a prefix
         # cache may seed the row and skip prefill), chunk_hook(engine,
@@ -169,7 +177,6 @@ class ServingEngine:
 
         # --- speculative machinery (opt-in) ---
         self.speculative = int(speculative)
-        self.spec_stats = {"verifies": 0, "accepted": 0}
         if self.speculative:
             if draft_program is None or draft_cfg is None \
                     or draft_params is None:
@@ -297,67 +304,91 @@ class ServingEngine:
 
     def step(self) -> list:
         """One continuous-batching iteration: evict / admit / chunk-prefill
-        / masked arena decode.  Returns the TokenEvents of this step."""
+        / masked arena decode.  Returns the TokenEvents of this step.
+
+        Each phase runs under a ``serve.*`` profiler span (see
+        docs/ARCHITECTURE.md, "Tracing a running engine"); the two
+        read-backs the host already waits on are ``serve.prefill.wait``
+        and ``serve.decode.wait``."""
         if self.released:
             raise RuntimeError("stepping a retired replica (arena released)")
         step = self.step_count
         self.step_count += 1
         new_events: list = []
+        c = self.counters
+        c["steps"] += 1
 
-        self.sched.plan_evictions(step)
-        for st in self.sched.admit(step):
-            self.cache = self._reset(self.cache, jnp.int32(st.slot))
+        with TraceAnnotation("serve.step", step=step):
+            with TraceAnnotation("serve.schedule"):
+                c["evicted"] += len(self.sched.plan_evictions(step))
+                joined = self.sched.admit(step)
+                c["admitted"] += len(joined)
+                for st in joined:
+                    self.cache = self._reset(self.cache, jnp.int32(st.slot))
+                    if self.speculative:
+                        self.draft_cache = self._reset(self.draft_cache,
+                                                       jnp.int32(st.slot))
+                        self._draft_pos[st.req.rid] = 0
+                    if self.admit_hook is not None:
+                        self.admit_hook(self, st)
+
+            # chunked prefill: bounded work per step, interleaved with decode
+            chunked = self.sched.chunk_candidates()
+            c["chunk_steps"] += bool(chunked)
+            for st in chunked:
+                with TraceAnnotation("serve.prefill", rid=st.req.rid,
+                                     pos=st.pos):
+                    toks = np.asarray(
+                        st.seq[st.pos:st.pos + self.prefill_chunk],
+                        np.int32)[None]
+                    last, self.cache = self._chunk(
+                        self.params, self.cache, jnp.asarray(toks),
+                        jnp.asarray([st.pos], jnp.int32), jnp.int32(st.slot))
+                    with TraceAnnotation("serve.prefill.wait"):
+                        last = int(last)
+                    appended, _ = self.sched.consume_chunk(
+                        st, self.prefill_chunk, last)
+                    if self.chunk_hook is not None:
+                        self.chunk_hook(self, st)
+                    if appended:
+                        new_events.append(self._event(st, step))
+
+            # masked width-1 decode over the whole arena: DECODE-phase rows
+            # feed their last generated token, sub-chunk PREFILL tails are
+            # teacher-forced (continuous batching: one iteration, all phases)
+            rows = self.sched.decode_rows(chunked)
+            spec_rows: list = []
             if self.speculative:
-                self.draft_cache = self._reset(self.draft_cache,
-                                               jnp.int32(st.slot))
-                self._draft_pos[st.req.rid] = 0
-            if self.admit_hook is not None:
-                self.admit_hook(self, st)
+                # DECODE-phase rows take the draft/verify path; PREFILL tails
+                # stay teacher-forced on the masked decode (nothing to draft)
+                spec_rows = [s for s in rows if s.phase == DECODE]
+                rows = [s for s in rows if s.phase != DECODE]
+            if rows:
+                with TraceAnnotation("serve.decode"):
+                    tok = np.zeros((self.n_slots, 1), np.int32)
+                    pos = np.zeros((self.n_slots,), np.int32)
+                    active = np.zeros((self.n_slots,), bool)
+                    for st in rows:
+                        tok[st.slot, 0] = self.sched.feed_token(st)
+                        pos[st.slot] = st.pos
+                        active[st.slot] = True
+                        c["forced_rows"] += st.phase == PREFILL
+                    c["decode_steps"] += 1
+                    c["decode_rows"] += len(rows)
+                    nxt, self.cache = self._decode(
+                        self.params, self.cache, jnp.asarray(tok),
+                        jnp.asarray(pos), jnp.asarray(active))
+                    with TraceAnnotation("serve.decode.wait"):
+                        nxt = np.asarray(nxt)
+                with TraceAnnotation("serve.commit"):
+                    for st in rows:
+                        appended, _ = self.sched.consume(st, int(nxt[st.slot]))
+                        if appended:
+                            new_events.append(self._event(st, step))
 
-        # chunked prefill: bounded work per step, interleaved with decode
-        chunked = self.sched.chunk_candidates()
-        for st in chunked:
-            toks = np.asarray(st.seq[st.pos:st.pos + self.prefill_chunk],
-                              np.int32)[None]
-            last, self.cache = self._chunk(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray([st.pos], jnp.int32), jnp.int32(st.slot))
-            appended, _ = self.sched.consume_chunk(
-                st, self.prefill_chunk, int(last))
-            if self.chunk_hook is not None:
-                self.chunk_hook(self, st)
-            if appended:
-                new_events.append(self._event(st, step))
-
-        # masked width-1 decode over the whole arena: DECODE-phase rows
-        # feed their last generated token, sub-chunk PREFILL tails are
-        # teacher-forced (continuous batching: one iteration, all phases)
-        rows = self.sched.decode_rows(chunked)
-        spec_rows: list = []
-        if self.speculative:
-            # DECODE-phase rows take the draft/verify path; PREFILL tails
-            # stay teacher-forced on the masked decode (nothing to draft)
-            spec_rows = [s for s in rows if s.phase == DECODE]
-            rows = [s for s in rows if s.phase != DECODE]
-        if rows:
-            tok = np.zeros((self.n_slots, 1), np.int32)
-            pos = np.zeros((self.n_slots,), np.int32)
-            active = np.zeros((self.n_slots,), bool)
-            for st in rows:
-                tok[st.slot, 0] = self.sched.feed_token(st)
-                pos[st.slot] = st.pos
-                active[st.slot] = True
-            nxt, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(active))
-            nxt = np.asarray(nxt)
-            for st in rows:
-                appended, _ = self.sched.consume(st, int(nxt[st.slot]))
-                if appended:
-                    new_events.append(self._event(st, step))
-
-        for st in spec_rows:
-            new_events.extend(self._spec_round(st, step))
+            for st in spec_rows:
+                with TraceAnnotation("serve.spec", rid=st.req.rid):
+                    new_events.extend(self._spec_round(st, step))
 
         self.events.extend(new_events)
         return new_events
@@ -436,8 +467,8 @@ class ServingEngine:
                                          jnp.int32(slot))
 
         appended, fin = self.sched.consume_spec(st, commit)
-        self.spec_stats["verifies"] += 1
-        self.spec_stats["accepted"] += appended
+        self.counters["verifies"] += 1
+        self.counters["accepted"] += appended
         if fin:
             self._draft_pos.pop(rid, None)
         base = len(st.generated) - appended

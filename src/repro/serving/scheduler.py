@@ -24,6 +24,7 @@ resumes exactly where it left off (recompute, never lose).
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
@@ -70,6 +71,11 @@ class RequestState:
     waiting_since: int = 0              # step enqueued / evicted (starvation)
     joined_step: int = -1               # step of last admission (residency)
     evictions: int = 0
+    # host-clock stamps (time.monotonic, the clock of TokenEvent.t): queue
+    # wait is t_admit - t_submit, admission to first token t_first - t_admit
+    t_submit: float = field(default_factory=time.monotonic)
+    t_admit: Optional[float] = None     # first admission
+    t_first: Optional[float] = None     # first generated token
 
     @property
     def seq(self) -> list:
@@ -119,6 +125,8 @@ class Scheduler:
             st.phase = PREFILL
             st.pos = 0
             st.joined_step = step
+            if st.t_admit is None:
+                st.t_admit = time.monotonic()
             self.active[st.req.rid] = st
             joined.append(st)
         return joined
@@ -240,6 +248,8 @@ class Scheduler:
         if st.pos < total:
             return False, False
         st.generated.append(int(next_tok))
+        if st.t_first is None:
+            st.t_first = time.monotonic()
         if st.phase == PREFILL:
             st.phase = DECODE
         if st.done:

@@ -10,7 +10,7 @@ from repro.configs.base import ShapeConfig, TrainConfig
 from repro.core import MeshSpec, compile_program
 from repro.data import SyntheticLM
 from repro.runtime import train_loop as tl
-from repro.runtime.fault_tolerance import StepTimer, run_with_recovery
+from repro.runtime.fault_tolerance import run_with_recovery
 
 MESH1 = MeshSpec(axis_sizes={"data": 1, "model": 1}, batch_axes=("data",))
 SMOKE = ShapeConfig("smoke", seq_len=16, global_batch=2, kind="train")
@@ -87,14 +87,6 @@ def test_run_with_recovery_survives_injected_failure(tmp_path):
     assert int(jax.device_get(final["step"])) == 6
     assert 4 in seen                      # the failed step was replayed
     assert ck.latest_step() == 6
-
-
-def test_straggler_detection():
-    t = StepTimer(window=20, threshold=3.0)
-    for i in range(20):
-        t.record(i, 0.10 + 0.001 * (i % 3))
-    assert t.record(20, 0.5) is True      # 5x median = straggler
-    assert t.stragglers and t.stragglers[0][0] == 20
 
 
 def test_recovery_reports_failure_and_restores_donated_state(tmp_path,

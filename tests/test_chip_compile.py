@@ -91,7 +91,7 @@ def _ffn_weights(one_chip):
 
 # The fused-decode kernels map each weight whole into VMEM (`_whole`):
 # at published width w_in alone is 896 x 9728 x 2 B = 17.4 MB.  ROADMAP
-# speed 5 tracks tiling them; until then these compiles are refused.
+# speed 6 tracks tiling them; until then these compiles are refused.
 _VMEM = pytest.mark.xfail(
     strict=True, raises=jax.errors.JaxRuntimeError,
     reason="RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ... "

@@ -91,7 +91,7 @@ def test_speculative_bit_identical_random_draft():
     reqs = mixed_requests(cfg, [9, 4, 13, 7], gen=8, seed=3)
     r_ref, _ = run_engine(cfg, reqs, max_len=32)
     r_spec, eng = run_engine(cfg, reqs, max_len=32, speculative=3)
-    assert eng.spec_stats["verifies"] > 0
+    assert eng.counters["verifies"] > 0
     assert r_spec == r_ref
     # the request budget is exact even when a verify over-proposes
     for r in reqs:
@@ -108,7 +108,7 @@ def test_speculative_self_draft_accepts_everything():
     r_spec, eng = run_engine(cfg, reqs, max_len=24, speculative=3,
                              draft_cfg=cfg, draft_seed=0)
     assert r_spec == r_ref
-    s = eng.spec_stats
+    s = eng.counters
     # gen=7: prefill emits token 0, spec commits 3+3 then hits the budget
     assert s["accepted"] == sum(r.max_new_tokens - 1 for r in reqs)
     assert s["accepted"] / s["verifies"] > 2.0
