@@ -117,22 +117,78 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.astype(q.dtype)
 
 
-def decode_attend(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                  kv_pos: jax.Array, pos: jax.Array, *,
-                  window: Optional[int] = None) -> jax.Array:
-    """One-token attention against the cache.
+LANES = 128     # a TPU tile's minor axis
 
-    q: (B, K, G, hd); k_cache/v_cache: (B, S, K, hd);
+
+def kv_group(n_kv_heads: int, head_dim: int) -> int:
+    """KV heads stored side by side in one cache row: as many as fill the
+    128 lanes and divide the head count (1 once head_dim fills them)."""
+    return math.gcd(n_kv_heads, max(1, LANES // head_dim))
+
+
+def kv_rows(x: jax.Array) -> jax.Array:
+    """K/V as projected, (..., S, K, hd), -> the cache's layout
+    (..., S, K/r, r*hd) with r = ``kv_group``: r KV heads side by side in
+    each lane-dense row.  A decode then writes one position's rows in the
+    arena's own layout (a head_dim under 128 lanes would otherwise make
+    the device lay the cache out positions-minor, and every write re-lay
+    it), and the attention reads them as they are."""
+    *lead, S, K, hd = x.shape
+    r = kv_group(K, hd)
+    return x.reshape(*lead, S, K // r, r * hd)
+
+
+def kv_heads(x: jax.Array, n_kv_heads: int) -> jax.Array:
+    """The cache's (..., S, K/r, r*hd) -> (..., S, K, hd) (``kv_rows``'s
+    inverse)."""
+    *lead, S, Kr, L = x.shape
+    return x.reshape(*lead, S, n_kv_heads, Kr * L // n_kv_heads)
+
+
+def _block_diag_heads(q: jax.Array, r: int) -> jax.Array:
+    """(B, T, K, G, hd) -> (B, T, K/r, r*G, r*hd): within each group of r
+    KV heads, every query head sits in the lanes of its own KV head with
+    zeros elsewhere, so one product with a (r*hd)-lane cache row contracts
+    each query head against its KV head only (the zeros add exact zeros).
+    r = 1 is a reshape."""
+    B, T, K, G, hd = q.shape
+    q = q.reshape(B, T, K // r, r, 1, G, hd)
+    own = jnp.eye(r, dtype=bool)[:, :, None, None]          # (r, r, 1, 1)
+    qb = jnp.where(own, q, jnp.zeros((), q.dtype))          # (.., r, r, G, hd)
+    return jnp.swapaxes(qb, -3, -2).reshape(B, T, K // r, r * G, r * hd)
+
+
+def chunk_attend(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                 kv_pos: jax.Array, pos: jax.Array, *,
+                 window: Optional[int] = None) -> jax.Array:
+    """Multi-token attention against the cache (chunked prefill).
+
+    q: (B, T, K, G, hd); k_cache/v_cache: (B, S, K/r, r*hd) (``kv_rows``);
     kv_pos: (B, S) logical position of each slot (-1 = empty);
-    pos: (B,) current absolute position.  Returns (B, K, G, hd).
+    pos: (B, T) absolute position of each query token.
+    Returns (B, T, K, G, hd).
+
+    One contraction over the full cache axis for every query position,
+    with the unnormalised-exp cast discipline below, so each position's
+    output is bit-identical to a single-token decode at that position
+    (``decode_attend`` is this at T = 1; the chunked-prefill ≡
+    whole-prompt invariant of tests/test_serving.py).  The chunk's own
+    K/V must already be in the cache; the kv_pos <= pos mask keeps every
+    query causal within the chunk.  Where r > 1 (head_dim under 128
+    lanes) a cache row holds r KV heads and each query head is read
+    through a block-diagonal query: the MXU pads a contraction under 128
+    lanes to 128 anyway.
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("bkgh,bskh->bkgs", q, k_cache,
+    B, T, K, G, hd = q.shape
+    Kr = k_cache.shape[2]
+    r = K // Kr
+    scale = 1.0 / math.sqrt(hd)
+    s = jnp.einsum("btjql,bsjl->btjqs", _block_diag_heads(q, r), k_cache,
                    preferred_element_type=jnp.float32) * scale
-    valid = (kv_pos >= 0) & (kv_pos[:, :] <= pos[:, None])
+    valid = (kv_pos[:, None, :] >= 0) & (kv_pos[:, None, :] <= pos[:, :, None])
     if window is not None:
-        valid &= (pos[:, None] - kv_pos) < window
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+        valid &= (pos[:, :, None] - kv_pos[:, None, :]) < window
+    s = jnp.where(valid[:, :, None, None, :], s, NEG_INF)
     # flash-identical arithmetic (one kv chunk): cast the UNnormalised
     # exp(s - m) to the cache dtype, matmul with f32 accumulation, divide
     # by the denominator afterwards.  Normalising before the bf16 cast
@@ -142,71 +198,73 @@ def decode_attend(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgs,bskh->bkgh", p.astype(v_cache.dtype), v_cache,
+    out = jnp.einsum("btjqs,bsjl->btjql", p.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
-    out = out / jnp.maximum(l, 1e-30)
+    # each query head keeps the lanes of its own KV head
+    out = jnp.diagonal(out.reshape(B, T, Kr, r, G, r, hd), axis1=3, axis2=5)
+    out = jnp.moveaxis(out, -1, 3).reshape(B, T, K, G, hd)
+    out = out / jnp.maximum(l, 1e-30).reshape(B, T, K, G, 1)
     return out.astype(q.dtype)
 
 
-def chunk_attend(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                 kv_pos: jax.Array, pos: jax.Array, *,
-                 window: Optional[int] = None) -> jax.Array:
-    """Multi-token attention against the cache (chunked prefill).
+def decode_attend(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                  kv_pos: jax.Array, pos: jax.Array, *,
+                  window: Optional[int] = None) -> jax.Array:
+    """One-token attention against the cache: ``chunk_attend`` at T = 1.
 
-    q: (B, T, K, G, hd); k_cache/v_cache: (B, S, K, hd);
+    q: (B, K, G, hd); k_cache/v_cache: (B, S, K/r, r*hd);
     kv_pos: (B, S) logical position of each slot (-1 = empty);
-    pos: (B, T) absolute position of each query token.
-    Returns (B, T, K, G, hd).
-
-    This is ``decode_attend`` vectorised over the T query positions —
-    same contraction over the full cache axis, same unnormalised-exp
-    cast discipline — so each position's output is bit-identical to a
-    single-token decode at that position (the chunked-prefill ≡
-    whole-prompt invariant of tests/test_serving.py).  The chunk's own
-    K/V must already be in the cache; the kv_pos <= pos mask keeps every
-    query causal within the chunk.
+    pos: (B,) current absolute position.  Returns (B, K, G, hd).
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    s = jnp.einsum("btkgh,bskh->btkgs", q, k_cache,
-                   preferred_element_type=jnp.float32) * scale
-    valid = (kv_pos[:, None, :] >= 0) & (kv_pos[:, None, :] <= pos[:, :, None])
-    if window is not None:
-        valid &= (pos[:, :, None] - kv_pos[:, None, :]) < window
-    s = jnp.where(valid[:, :, None, None, :], s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("btkgs,bskh->btkgh", p.astype(v_cache.dtype), v_cache,
-                     preferred_element_type=jnp.float32)
-    out = out / jnp.maximum(l, 1e-30)
-    return out.astype(q.dtype)
+    return chunk_attend(q[:, None], k_cache, v_cache, kv_pos, pos[:, None],
+                        window=window)[:, 0]
 
 
 def init_kv_cache(cfg: AttentionConfig, batch: int, length: int,
                   dtype=jnp.bfloat16) -> dict:
+    """K/V of (batch, size, K/r, r*hd) (``kv_rows``) and the logical
+    position of each of the `size` slots (-1 = empty)."""
     K, hd = cfg.n_kv_heads, cfg.head_dim
+    r = kv_group(K, hd)
     size = min(length, cfg.window) if cfg.window else length
     return {
-        "k": jnp.zeros((batch, size, K, hd), dtype),
-        "v": jnp.zeros((batch, size, K, hd), dtype),
+        "k": jnp.zeros((batch, size, K // r, r * hd), dtype),
+        "v": jnp.zeros((batch, size, K // r, r * hd), dtype),
         "pos": jnp.full((batch, size), -1, jnp.int32),
     }
 
 
 def update_cache(cache: dict, k1: jax.Array, v1: jax.Array,
-                 pos: jax.Array) -> dict:
+                 pos: jax.Array, *, layer: Optional[jax.Array] = None,
+                 active: Optional[jax.Array] = None) -> dict:
     """Insert one token at logical position `pos` (ring-buffered if windowed).
 
-    k1/v1: (B, K, hd); pos: (B,) — same position across batch in practice,
-    but kept per-row for generality.
+    k1/v1: (B, K, hd), written as the position's (K/r, r*hd) rows;
+    pos: (B,) — same position across batch in practice, but kept per-row
+    for generality.
+
+    layer: index into a leading layer-group axis, for a cache stacked over
+    groups (leaves (n_groups, B, ...)); the position is scattered into
+    that layer of the stack, so a donated arena is written in place.
+    active: (B,) bool; a row where it is False is not written at all (its
+    index is sent out of range and the scatter drops it), so that row of
+    the cache stays bit-identical with no select over the arena.
     """
-    size = cache["k"].shape[1]
+    size = cache["pos"].shape[-1]
     slot = pos % size                                     # ring index (B,)
-    b = jnp.arange(k1.shape[0])
-    k = cache["k"].at[b, slot].set(k1.astype(cache["k"].dtype))
-    v = cache["v"].at[b, slot].set(v1.astype(cache["v"].dtype))
-    kv_pos = cache["pos"].at[b, slot].set(pos)
-    return {"k": k, "v": v, "pos": kv_pos}
+    if active is not None:
+        slot = jnp.where(active, slot, size)
+    B, Kr = k1.shape[0], cache["k"].shape[-2]
+    idx = (jnp.arange(B), slot)
+    if layer is not None:
+        idx = (layer,) + idx
+
+    def put(a, x):
+        return a.at[idx].set(x.astype(a.dtype), mode="drop")
+
+    return {"k": put(cache["k"], k1.reshape(B, Kr, -1)),
+            "v": put(cache["v"], v1.reshape(B, Kr, -1)),
+            "pos": put(cache["pos"], pos)}
 
 
 def update_cache_chunk(cache: dict, k: jax.Array, v: jax.Array,
@@ -220,11 +278,13 @@ def update_cache_chunk(cache: dict, k: jax.Array, v: jax.Array,
     vectorised insert would let a later in-chunk token overwrite a ring
     slot an earlier query still needs, silently dropping K/V entries.
     """
-    size = cache["k"].shape[1]
+    size = cache["pos"].shape[1]
     slot = pos % size                                     # (B, T)
-    b = jnp.arange(k.shape[0])[:, None]
-    kc = cache["k"].at[b, slot].set(k.astype(cache["k"].dtype))
-    vc = cache["v"].at[b, slot].set(v.astype(cache["v"].dtype))
+    B, T = pos.shape
+    rows = (B, T, cache["k"].shape[2], -1)
+    b = jnp.arange(B)[:, None]
+    kc = cache["k"].at[b, slot].set(k.reshape(rows).astype(cache["k"].dtype))
+    vc = cache["v"].at[b, slot].set(v.reshape(rows).astype(cache["v"].dtype))
     kv_pos = cache["pos"].at[b, slot].set(pos)
     return {"k": kc, "v": vc, "pos": kv_pos}
 

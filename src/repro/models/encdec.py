@@ -15,8 +15,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.attention import (attention_block, attn_params,
-                                    decode_attend, init_kv_cache, split_qkv,
-                                    update_cache)
+                                    decode_attend, init_kv_cache, kv_rows,
+                                    split_qkv, update_cache)
 from repro.models.layers import (Sharder, apply_norm, embed, mlp,
                                  mlp_params, norm_params)
 
@@ -170,10 +170,8 @@ def init_cache(cfg: ModelConfig, params_or_shapes: dict, batch: int,
         init_kv_cache(a, batch, max_len))
     K, hd = a.n_kv_heads, a.head_dim
     Se = cfg.enc_seq
-    cross = {
-        "k": jnp.zeros((L, batch, Se, K, hd), jnp.bfloat16),
-        "v": jnp.zeros((L, batch, Se, K, hd), jnp.bfloat16),
-    }
+    zeros = kv_rows(jnp.zeros((L, batch, Se, K, hd), jnp.bfloat16))
+    cross = {"k": zeros, "v": zeros}
     return {"self": self_c, "cross": cross}
 
 
@@ -188,8 +186,8 @@ def precompute_cross_kv(cfg: ModelConfig, params: dict, enc_out: jax.Array,
         kv = sh.dot("cross_qkv", enc_out, w[:, H * hd:], constrain=False)
         k, v = jnp.split(kv, 2, axis=-1)
         B, Se = enc_out.shape[:2]
-        return (k.reshape(B, Se, K, hd).astype(jnp.bfloat16),
-                v.reshape(B, Se, K, hd).astype(jnp.bfloat16))
+        return (kv_rows(k.reshape(B, Se, K, hd)).astype(jnp.bfloat16),
+                kv_rows(v.reshape(B, Se, K, hd)).astype(jnp.bfloat16))
 
     ks, vs = jax.lax.map(one, params["dec_groups"])
     return {"k": ks, "v": vs}

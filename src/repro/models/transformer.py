@@ -24,7 +24,8 @@ from repro.engine.dispatch import pe_fused_attn_unit, pe_fused_ffn
 from repro.models import ssm as ssm_mod
 from repro.models.attention import (attention_block, attn_params,
                                     chunk_attend, decode_attend,
-                                    init_kv_cache, split_qkv, update_cache,
+                                    init_kv_cache, kv_heads, kv_rows,
+                                    split_qkv, update_cache,
                                     update_cache_chunk)
 from repro.models.layers import (Sharder, act_fn, apply_norm, apply_rope,
                                  embed, lm_logits, mlp, mlp_params,
@@ -185,8 +186,8 @@ def _unit_forward(cfg: ModelConfig, x, uparams: dict, unit: UnitDesc,
             k = apply_rope(k, positions, a.rope_theta)
             size = min(h.shape[1], a.window) if a.window else h.shape[1]
             cache["attn"] = {
-                "k": k[:, -size:].astype(jnp.bfloat16),
-                "v": v[:, -size:].astype(jnp.bfloat16),
+                "k": kv_rows(k[:, -size:]).astype(jnp.bfloat16),
+                "v": kv_rows(v[:, -size:]).astype(jnp.bfloat16),
                 "pos": jnp.broadcast_to(
                     positions[-size:][None].astype(jnp.int32),
                     (h.shape[0], size)),
@@ -363,9 +364,49 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     return jax.tree.map(lambda x: jnp.broadcast_to(x, (ng,) + x.shape), one())
 
 
+def _layer(stack, g):
+    """Layer group `g` of a cache subtree stacked over groups."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, g, keepdims=False), stack)
+
+
+def _decode_attend_layer(cfg: ModelConfig, q, k, v, stack: dict, pos,
+                         g, active):
+    """Write this token's K/V into layer `g` of the stacked attention
+    cache (active rows only, in place) and attend over that layer.
+    q: (B, 1, K, G, hd); k/v: (B, 1, K, hd).  Returns (out (B, 1, K*G*hd),
+    new stack)."""
+    c = update_cache(stack, k[:, 0], v[:, 0], pos, layer=g, active=active)
+    lc = _layer(c, g)
+    out = decode_attend(q[:, 0], lc["k"], lc["v"], lc["pos"], pos,
+                        window=cfg.attention.window)
+    return out.reshape(q.shape[0], 1, -1), c
+
+
+def _decode_state_layer(cfg: ModelConfig, h, uparams: dict, unit: UnitDesc,
+                        sh: Sharder, stack: dict, g, active):
+    """One recurrent step (rwkv6 / mamba) on layer `g` of the stacked
+    state.  Rows where `active` is False keep their old state (a row
+    select: the state is O(slots x heads x hd^2), not O(max_len))."""
+    block = ssm_mod.rwkv_block if unit.mixer == "rwkv6" \
+        else ssm_mod.mamba_block
+    key = "rwkv" if unit.mixer == "rwkv6" else "mamba"
+    old = _layer(stack[key], g)
+    mix, st = block(cfg, h, uparams[key], sh, old)
+    if active is not None:
+        st = jax.tree.map(
+            lambda n, o: jnp.where(
+                active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o), st, old)
+    new = jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+            a, n.astype(a.dtype), g, 0), stack[key], st)
+    return mix, {**stack, key: new}
+
+
 def _unit_decode(cfg: ModelConfig, x, uparams: dict, unit: UnitDesc,
-                 sh: Sharder, cache: dict, pos: jax.Array):
-    """x: (B, 1, d); pos: (B,) absolute position.  Returns (x, new_cache)."""
+                 sh: Sharder, cache: dict, pos: jax.Array, g, active):
+    """x: (B, 1, d); pos: (B,) absolute position; cache: this unit's
+    caches stacked over groups, `g` the group.  Returns (x, new_cache)."""
     h = apply_norm(cfg, x, uparams.get("norm1"))
     new_cache = dict(cache)
     if unit.mixer == "attn":
@@ -378,18 +419,12 @@ def _unit_decode(cfg: ModelConfig, x, uparams: dict, unit: UnitDesc,
         q = apply_rope(q.reshape(B, 1, K_ * G, hd), posb,
                        a.rope_theta).reshape(B, 1, K_, G, hd)
         k = apply_rope(k, posb, a.rope_theta)
-        c = update_cache(cache["attn"], k[:, 0], v[:, 0], pos)
-        out = decode_attend(q[:, 0], c["k"], c["v"], c["pos"], pos,
-                            window=a.window)
-        out = out.reshape(B, 1, -1)
+        out, new_cache["attn"] = _decode_attend_layer(
+            cfg, q, k, v, cache["attn"], pos, g, active)
         mix = sh.dot("attn_o", out, uparams["attn"]["o"])
-        new_cache["attn"] = c
-    elif unit.mixer == "rwkv6":
-        mix, st = ssm_mod.rwkv_block(cfg, h, uparams["rwkv"], sh, cache["rwkv"])
-        new_cache["rwkv"] = st
     else:
-        mix, st = ssm_mod.mamba_block(cfg, h, uparams["mamba"], sh, cache["mamba"])
-        new_cache["mamba"] = st
+        mix, new_cache = _decode_state_layer(cfg, h, uparams, unit, sh,
+                                             cache, g, active)
     x = x + mix
     h2 = apply_norm(cfg, x, uparams.get("norm2"))
     if unit.ffn == "moe":
@@ -420,7 +455,7 @@ def _mlp_fused_ref(cfg: ModelConfig, x, w_in, w_out):
 
 
 def _unit_decode_fused(cfg: ModelConfig, x, uparams: dict, unit: UnitDesc,
-                       sh: Sharder, cache: dict, pos: jax.Array):
+                       sh: Sharder, cache: dict, pos: jax.Array, g, active):
     """Fused per-layer decode: the unit as ONE dispatch, not four.
 
     On the pallas backend the attention projections, cache append, paged
@@ -435,7 +470,8 @@ def _unit_decode_fused(cfg: ModelConfig, x, uparams: dict, unit: UnitDesc,
     loop — the parity oracle the megakernel is validated against.
     """
     if sh.backend == "pallas":
-        return _unit_decode_fused_pallas(cfg, x, uparams, unit, sh, cache, pos)
+        return _unit_decode_fused_pallas(cfg, x, uparams, unit, sh, cache,
+                                         pos, g, active)
     h = apply_norm(cfg, x, uparams.get("norm1"))
     new_cache = dict(cache)
     if unit.mixer == "attn":
@@ -448,18 +484,12 @@ def _unit_decode_fused(cfg: ModelConfig, x, uparams: dict, unit: UnitDesc,
         q = apply_rope(q.reshape(B, 1, K_ * G, hd), posb,
                        a.rope_theta).reshape(B, 1, K_, G, hd)
         k = apply_rope(k, posb, a.rope_theta)
-        c = update_cache(cache["attn"], k[:, 0], v[:, 0], pos)
-        out = decode_attend(q[:, 0], c["k"], c["v"], c["pos"], pos,
-                            window=a.window)
-        out = out.reshape(B, 1, -1)
+        out, new_cache["attn"] = _decode_attend_layer(
+            cfg, q, k, v, cache["attn"], pos, g, active)
         mix = out @ uparams["attn"]["o"].astype(out.dtype)
-        new_cache["attn"] = c
-    elif unit.mixer == "rwkv6":
-        mix, st = ssm_mod.rwkv_block(cfg, h, uparams["rwkv"], sh, cache["rwkv"])
-        new_cache["rwkv"] = st
     else:
-        mix, st = ssm_mod.mamba_block(cfg, h, uparams["mamba"], sh, cache["mamba"])
-        new_cache["mamba"] = st
+        mix, new_cache = _decode_state_layer(cfg, h, uparams, unit, sh,
+                                             cache, g, active)
     x = x + mix
     h2 = apply_norm(cfg, x, uparams.get("norm2"))
     if unit.ffn == "moe":
@@ -483,16 +513,23 @@ def _fused_norm_args(cfg: ModelConfig, uparams: dict, key: str):
 
 def _unit_decode_fused_pallas(cfg: ModelConfig, x, uparams: dict,
                               unit: UnitDesc, sh: Sharder, cache: dict,
-                              pos: jax.Array):
-    """Lower the unit onto the decode_fused megakernel (pallas backend)."""
+                              pos: jax.Array, g, active):
+    """Lower the unit onto the decode_fused megakernel (pallas backend).
+
+    The kernel appends to its own copy of layer `g`'s rows; the appended
+    position is then written into the stacked arena like every other
+    decode (``update_cache``: active rows only, in place)."""
     new_cache = dict(cache)
     n1, nk = _fused_norm_args(cfg, uparams, "norm1")
     n2, _ = _fused_norm_args(cfg, uparams, "norm2")
     dense = unit.ffn == "dense"
     if unit.mixer == "attn":
         a = cfg.attention
-        y2, c = pe_fused_attn_unit(
-            x[:, 0], cache["attn"], pos,
+        lc = _layer(cache["attn"], g)
+        y2, c = pe_fused_attn_unit(       # the kernel reads (B, S, K, hd)
+            x[:, 0], {"k": kv_heads(lc["k"], a.n_kv_heads),
+                      "v": kv_heads(lc["v"], a.n_kv_heads),
+                      "pos": lc["pos"]}, pos,
             norm1=n1, qkv_w=uparams["attn"]["qkv"],
             qkv_bias=uparams["attn"].get("qkv_bias"),
             o_w=uparams["attn"]["o"],
@@ -503,21 +540,19 @@ def _unit_decode_fused_pallas(cfg: ModelConfig, x, uparams: dict,
             rope_theta=a.rope_theta, window=a.window,
             norm_kind=nk, act=cfg.act, with_ffn=dense,
             word=sh.word("attn_qkv"), interpret=sh.interpret)
-        new_cache["attn"] = c
+        b = jnp.arange(pos.shape[0])
+        slot = pos % c["pos"].shape[-1]
+        new_cache["attn"] = update_cache(
+            cache["attn"], c["k"][b, slot], c["v"][b, slot], pos,
+            layer=g, active=active)
         if dense:
             return y2[:, None], new_cache
         x = y2[:, None]
     else:
         # SSM recurrence: a VPU/state word — stays on its per-op path
         h = apply_norm(cfg, x, uparams.get("norm1"))
-        if unit.mixer == "rwkv6":
-            mix, st = ssm_mod.rwkv_block(cfg, h, uparams["rwkv"], sh,
-                                         cache["rwkv"])
-            new_cache["rwkv"] = st
-        else:
-            mix, st = ssm_mod.mamba_block(cfg, h, uparams["mamba"], sh,
-                                          cache["mamba"])
-            new_cache["mamba"] = st
+        mix, new_cache = _decode_state_layer(cfg, h, uparams, unit, sh,
+                                             cache, g, active)
         x = x + mix
     if unit.ffn == "moe":
         h2 = apply_norm(cfg, x, uparams.get("norm2"))
@@ -626,8 +661,15 @@ def chunk_step(cfg: ModelConfig, params: dict, tokens: jax.Array,
 
 def decode_step(cfg: ModelConfig, params: dict, tokens: jax.Array,
                 cache: dict, pos: jax.Array, sh: Sharder,
-                *, compute_dtype=jnp.bfloat16, fused: bool = False):
+                *, compute_dtype=jnp.bfloat16, fused: bool = False,
+                active=None):
     """One serve step.  tokens: (B, 1); pos: (B,).  Returns (logits, cache).
+
+    active: optional (B,) bool row mask; rows where it is False leave
+    their cache rows untouched (their logits are garbage).  None writes
+    every row.  The stacked cache rides the group loop as carried state
+    and each layer's new position is scattered into it in place, so a
+    step moves one position per active row, not the cache.
 
     fused=True routes each unit through the fused-decode path (one
     dispatch per layer — the decode_fused megakernel on the pallas
@@ -637,16 +679,16 @@ def decode_step(cfg: ModelConfig, params: dict, tokens: jax.Array,
     unit_fn = _unit_decode_fused if fused else _unit_decode
     x = embed(tokens, params["embed"]["table"], sh).astype(compute_dtype)
 
-    def group_step(x, scanned):
-        gparams, gcache = scanned
-        new_c = {}
+    def group_step(carry, gparams):
+        x, cache, g = carry
+        cache = dict(cache)
         for i, u in enumerate(pattern):
-            x, c = unit_fn(cfg, x, gparams[f"u{i}"], u, sh,
-                           gcache[f"u{i}"], pos)
-            new_c[f"u{i}"] = c
-        return x, new_c
+            x, cache[f"u{i}"] = unit_fn(cfg, x, gparams[f"u{i}"], u, sh,
+                                        cache[f"u{i}"], pos, g, active)
+        return (x, cache, g + 1), None
 
-    x, new_caches = jax.lax.scan(group_step, x, (params["groups"], cache))
+    (x, cache, _), _ = jax.lax.scan(
+        group_step, (x, cache, jnp.zeros((), jnp.int32)), params["groups"])
     x = apply_norm(cfg, x, params.get("final_norm"))
     logits = lm_logits(x, cfg, params, sh)
-    return logits, new_caches
+    return logits, cache

@@ -265,12 +265,12 @@ def make_decode_step(cfg: ModelConfig, program: Program, mesh=None,
     sh = PEContext(mesh, program,
                    backend=kernel_backend).with_phase(Phase.DECODE)
 
-    def decode(params, cache, tokens, pos):
+    def decode(params, cache, tokens, pos, active=None):
         if cfg.family == "audio":
             return encdec.decode_step(cfg, params, tokens, cache, pos, sh,
                                       compute_dtype=policy.ff_dtype)
         return tfm.decode_step(cfg, params, tokens, cache, pos, sh,
-                               compute_dtype=policy.ff_dtype)
+                               compute_dtype=policy.ff_dtype, active=active)
 
     return decode
 
@@ -290,9 +290,10 @@ def make_fused_decode_step(cfg: ModelConfig, program: Program, mesh=None,
     sh = PEContext(mesh, program,
                    backend=kernel_backend).with_phase(Phase.DECODE)
 
-    def decode(params, cache, tokens, pos):
+    def decode(params, cache, tokens, pos, active=None):
         return tfm.decode_step(cfg, params, tokens, cache, pos, sh,
-                               compute_dtype=policy.ff_dtype, fused=True)
+                               compute_dtype=policy.ff_dtype, fused=True,
+                               active=active)
 
     return decode
 
@@ -311,9 +312,9 @@ def make_draft_step(cfg: ModelConfig, program: Program, mesh=None,
     sh = PEContext(mesh, program,
                    backend=kernel_backend).with_phase(Phase.DRAFT)
 
-    def draft(params, cache, tokens, pos):
+    def draft(params, cache, tokens, pos, active=None):
         return tfm.decode_step(cfg, params, tokens, cache, pos, sh,
-                               compute_dtype=policy.ff_dtype)
+                               compute_dtype=policy.ff_dtype, active=active)
 
     return draft
 

@@ -12,8 +12,9 @@ matvec).  This package schedules both onto one fixed cache arena:
   (QUEUED -> PREFILL -> DECODE -> FINISHED, with eviction back to
   QUEUED under arena pressure); chunked prefill is interleaved with
   decode so long prompts never stall the decode batch.
-- :mod:`engine` — the array work: one jitted masked decode over the
-  whole arena per step plus per-slot prefill chunk steps, both routed
+- :mod:`engine` — the array work: one jitted masked decode over every
+  arena row per step (only active rows' new positions are written, in
+  place) plus per-slot prefill chunk steps, both routed
   through ``PEContext`` under the PREFILL/DECODE program words.
 - :mod:`trace` — synthetic request traces (Poisson, bursty and
   diurnal/heavy-tail arrivals) for examples and the throughput

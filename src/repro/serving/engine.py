@@ -3,11 +3,14 @@
 Two compiled step functions, both routed through ``PEContext`` under the
 serving program words (core/phases.py):
 
-- ``_decode``: one masked width-1 decode over the WHOLE arena (fixed
-  shape, always the same jit).  Inactive rows compute garbage and their
-  cache rows are restored bit-exactly afterwards (``jnp.where`` on the
-  batch axis) — fixed shapes beat gather/scatter recompiles, and masked
-  rows cost only FLOPs, never correctness.  Runs the DECODE word:
+- ``_decode``: one masked width-1 decode over every arena row (fixed
+  shape, always the same jit).  Inactive rows compute garbage that is
+  never written: ``decode_step`` takes the ``active`` mask and scatters
+  each layer's new K/V position into the donated arena for active rows
+  only (an inactive row's index is out of range and dropped), and
+  recurrent states keep their old rows by a per-layer row select — so a
+  step moves one position per active row, not the arena, and masked rows
+  cost only FLOPs, never correctness.  Runs the DECODE word:
   bandwidth-oriented matvec, no SR entropy.
 - ``_chunk``: one ``prefill_chunk``-wide prompt chunk for a single slot
   (dynamic slice on the arena's batch axis, slot index traced — one
@@ -23,7 +26,7 @@ Two opt-in fast paths preserve that contract:
   DECODE words select the per-layer megakernel (kernels/decode_fused.py)
   and ``_decode`` runs one dispatch per LAYER instead of one per op.
   Masked-arena semantics are unchanged — inactive rows still compute
-  garbage that ``jnp.where`` discards.
+  garbage that is never written to the arena.
 - speculative decoding (``build_engine(speculative=k)``): a small draft
   model proposes k-1 tokens under the DRAFT program word, the big model
   verifies all k feeds in ONE PREFILL-shaped chunk (``make_chunk_step``
@@ -137,13 +140,8 @@ class ServingEngine:
                                       kernel_backend=kernel_backend)
 
         def _decode(params, cache, tok, pos, active):
-            logits, new_cache = decode_fn(params, cache, tok, pos)
-            new_cache = jax.tree.map(
-                lambda new, old: jnp.where(
-                    active.reshape((1, n_slots) + (1,) * (new.ndim - 2)),
-                    new, old),
-                new_cache, cache)
-            return jnp.argmax(logits[:, 0], -1).astype(jnp.int32), new_cache
+            logits, cache = decode_fn(params, cache, tok, pos, active)
+            return jnp.argmax(logits[:, 0], -1).astype(jnp.int32), cache
 
         def _chunk(params, cache, tokens, pos0, slot):
             row = jax.tree.map(
@@ -192,14 +190,9 @@ class ServingEngine:
                                           kernel_backend=kernel_backend)
 
             def _draft(params, cache, tok, pos, active):
-                logits, new_cache = draft_fn(params, cache, tok, pos)
-                new_cache = jax.tree.map(
-                    lambda new, old: jnp.where(
-                        active.reshape((1, n_slots) + (1,) * (new.ndim - 2)),
-                        new, old),
-                    new_cache, cache)
+                logits, cache = draft_fn(params, cache, tok, pos, active)
                 return (jnp.argmax(logits[:, 0], -1).astype(jnp.int32),
-                        new_cache)
+                        cache)
 
             def _verify(params, cache, tokens, pos0, slot):
                 # PREFILL-shaped chunk over the request's arena row; the

@@ -6,8 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.attention import (decode_attend, flash_attention,
-                                    init_kv_cache, update_cache)
+from repro.models.attention import (chunk_attend, decode_attend,
+                                    flash_attention, init_kv_cache, kv_group,
+                                    kv_heads, kv_rows, update_cache,
+                                    update_cache_chunk)
 from repro.configs.base import AttentionConfig
 
 
@@ -95,3 +97,69 @@ def test_ring_buffer_window_decode():
                             pos, window=4)
         np.testing.assert_allclose(np.asarray(out), np.asarray(full[:, t]),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("K,G,hd,group", [
+    (2, 7, 64, 2),      # qwen2-0.5b: both KV heads share one 128-lane row
+    (4, 2, 64, 2),      # two rows of two heads
+    (2, 1, 128, 1),     # head_dim fills the lanes: one head a row
+    (3, 2, 64, 1),      # an odd head count cannot pair up
+])
+def test_kv_row_groups_match_naive(K, G, hd, group):
+    """Cache rows hold ``kv_group`` KV heads side by side; single-token
+    decode, the chunk path, and a masked in-place write into a stacked
+    cache all read and write each head's own lanes."""
+    assert kv_group(K, hd) == group
+    a = AttentionConfig(n_heads=K * G, n_kv_heads=K, head_dim=hd)
+    B, S = 2, 6
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q = jax.random.normal(ks[0], (B, S, K, G, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, K, hd))
+    v = jax.random.normal(ks[2], (B, S, K, hd))
+    full = np.asarray(naive_attention(q, k, v, causal=True))
+    assert np.array_equal(np.asarray(kv_heads(kv_rows(k), K)), np.asarray(k))
+
+    cache = init_kv_cache(a, B, S, dtype=jnp.float32)
+    assert cache["k"].shape == (B, S, K // group, group * hd)
+    for t in range(S):
+        pos = jnp.full((B,), t, jnp.int32)
+        cache = update_cache(cache, k[:, t], v[:, t], pos)
+        out = decode_attend(q[:, t], cache["k"], cache["v"], cache["pos"],
+                            pos)
+        np.testing.assert_allclose(np.asarray(out), full[:, t],
+                                   rtol=1e-4, atol=1e-4)
+    assert np.array_equal(np.asarray(cache["k"]), np.asarray(kv_rows(k)))
+
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    c = update_cache_chunk(init_kv_cache(a, B, S, dtype=jnp.float32),
+                           k, v, pos)
+    out = chunk_attend(q, c["k"], c["v"], c["pos"], pos)
+    np.testing.assert_allclose(np.asarray(out), full, rtol=1e-4, atol=1e-4)
+
+    # layer 1 of a two-layer stack, row 0 active, row 1 masked off
+    stack = jax.tree.map(lambda x: jnp.stack([x, x + 1]), cache)
+    new = update_cache(stack, -k[:, 0], -v[:, 0], jnp.asarray([2, 3]),
+                       layer=jnp.int32(1), active=jnp.asarray([True, False]))
+    want = np.asarray(stack["k"]).copy()
+    want[1, 0, 2] = np.asarray(kv_rows(-k[:, :1]))[0, 0]
+    assert np.array_equal(np.asarray(new["k"]), want)
+    assert np.asarray(new["pos"])[1, 0, 2] == 2
+    assert np.array_equal(np.asarray(new["pos"])[:, 1],
+                          np.asarray(stack["pos"])[:, 1])
+
+
+@pytest.mark.parametrize("K,hd", [(16, 128), (16, 64), (2, 64)])
+def test_attend_flops_grow_with_the_lane_group_not_the_heads(K, hd):
+    """The block-diagonal query spans one cache row's ``kv_group`` heads
+    only: decode attention costs at most that factor over the plain
+    per-head contraction (2 x 2 x B x H x S x hd), whatever K is."""
+    B, G, S = 2, 2, 256
+    args = (jax.ShapeDtypeStruct((B, K, G, hd), jnp.bfloat16),
+            *[jax.ShapeDtypeStruct((B, S, K // kv_group(K, hd),
+                                    kv_group(K, hd) * hd), jnp.bfloat16)] * 2,
+            jax.ShapeDtypeStruct((B, S), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32))
+    cost = jax.jit(decode_attend).lower(*args).compile().cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    plain = 2 * 2 * B * K * G * S * hd
+    assert plain <= cost["flops"] < 1.25 * kv_group(K, hd) * plain

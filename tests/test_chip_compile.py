@@ -1,4 +1,5 @@
-"""Main-path Pallas kernels compile for a TPU v5e at qwen2-0.5b widths.
+"""Main-path Pallas kernels, and the serving engine's decode step, compile
+for a TPU v5e at qwen2-0.5b widths.
 
 The TPU compiler is installed with jaxlib, so each kernel is lowered and
 compiled here for one chip of a described-but-not-attached ``v5e:2x2``
@@ -135,3 +136,67 @@ def test_fused_attn_unit_compiles(one_chip):
              _sds((SLOTS,), jnp.int32, one_chip),
              _sds((D, qn), bf, one_chip), _sds((HEADS * HEAD_DIM, D), bf,
                                                one_chip), w_in, w_out)
+
+
+def _engine_decode(one_chip, arch):
+    """The engine's masked decode (``_decode``) for `arch` at published
+    widths, 2 layers, SLOTS x MAX_LEN: (compiled program, count of copy or
+    select ops as large as one layer's K leaf (a relayout of the arena, or
+    a row-restoring ``jnp.where`` over it), that leaf's bytes)."""
+    import dataclasses
+    import math
+    import re
+
+    from repro.configs import get_config
+    from repro.configs.base import ShapeConfig
+    from repro.core import MeshSpec, compile_program
+    from repro.runtime import train_loop as tl
+    from repro.serving.engine import ServingEngine
+
+    cfg = dataclasses.replace(get_config(arch), n_layers=2)
+    shape = ShapeConfig("serve", seq_len=MAX_LEN, global_batch=SLOTS,
+                        kind="decode")
+    program = compile_program(cfg, shape,
+                              MeshSpec(axis_sizes={"data": 1, "model": 1}))
+    eng = ServingEngine(cfg, program, None, n_slots=SLOTS, max_len=MAX_LEN,
+                        prefill_chunk=256, kernel_backend="pallas")
+    params = jax.eval_shape(
+        lambda k: tl.cast_params(tl.model_module(cfg).init(k, cfg),
+                                 jnp.bfloat16), jax.random.PRNGKey(0))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    compiled = eng._decode.lower(
+        on_chip(params), on_chip(eng.cache),
+        _sds((SLOTS, 1), jnp.int32, one_chip),
+        _sds((SLOTS,), jnp.int32, one_chip),
+        _sds((SLOTS,), jnp.bool_, one_chip)).compile()
+    a = cfg.attention
+    k_leaf = SLOTS * MAX_LEN * a.n_kv_heads * a.head_dim * 2   # bf16
+    width = {"pred": 1, "bf16": 2, "s32": 4, "f32": 4}
+    arena_ops = [
+        op for dtype, dims, op in re.findall(
+            r"= (\w+)\[([\d,]+)\]\S* (copy|select)\(", compiled.as_text())
+        if width.get(dtype, 4) * math.prod(map(int, dims.split(",")))
+        >= k_leaf]
+    return compiled, len(arena_ops), k_leaf
+
+
+def test_engine_decode_moves_no_arena_copy(one_chip):
+    """The engine's masked decode writes one position per active row into
+    the donated arena in place: the compiled program holds no copy or
+    select shaped like an arena leaf, and no temporary as large as one
+    layer's K leaf.  At this size a copy can sit in on-chip memory outside
+    the temporaries, so the ops are counted in the program text too."""
+    compiled, arena_ops, k_leaf = _engine_decode(one_chip, "qwen2-0.5b")
+    assert arena_ops == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < k_leaf
+
+
+def test_engine_decode_moves_no_arena_copy_at_head_dim_128(one_chip):
+    """The same at olmo-1b's widths (16 KV heads of 128: one head a cache
+    row, where qwen2-0.5b's two heads of 64 share one)."""
+    compiled, arena_ops, k_leaf = _engine_decode(one_chip, "olmo-1b")
+    assert arena_ops == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < k_leaf

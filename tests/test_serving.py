@@ -182,6 +182,67 @@ def test_windowed_ring_wrap_chunked_prefill_matches_single_shot():
         assert results[r.rid] == want, r.rid
 
 
+def _masked_decode_config(arch: str):
+    """Reduced `arch`; "qwen2-0.5b-window" is qwen2 with an 8-position
+    sliding-window ring cache."""
+    import dataclasses
+    if arch != "qwen2-0.5b-window":
+        return get_reduced(arch)
+    base = get_reduced("qwen2-0.5b")
+    return dataclasses.replace(
+        base, attention=dataclasses.replace(base.attention, window=8))
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "jamba-v0.1-52b",
+                                  "qwen2-0.5b-window"])
+def test_masked_decode_writes_active_rows_only(arch):
+    """The engine's decode writes the new position of each ACTIVE row in
+    place: every inactive row's cache leaves come back bit-identical, and
+    the active rows equal an every-row decode whose inactive rows are then
+    restored by a row select."""
+    MAX_LEN, SLOTS = 16, 4
+    cfg = _masked_decode_config(arch)
+    shape = ShapeConfig("serve", seq_len=MAX_LEN, global_batch=SLOTS,
+                        kind="decode")
+    program = compile_program(cfg, shape, MESH1)
+    params = tl.cast_params(tfm.init(jax.random.PRNGKey(0), cfg),
+                            jnp.bfloat16)
+    engine = ServingEngine(cfg, program, params, n_slots=SLOTS,
+                           max_len=MAX_LEN)
+    # a full arena: random K/V and states, positions in [-1, MAX_LEN)
+    leaves, tree = jax.tree.flatten(engine.cache)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(leaves))
+    cache = jax.tree.unflatten(tree, [
+        jax.random.randint(k, a.shape, -1, MAX_LEN, a.dtype)
+        if jnp.issubdtype(a.dtype, jnp.integer)
+        else jax.random.normal(k, a.shape, a.dtype)
+        for k, a in zip(keys, leaves)])
+    tok = jnp.asarray([[3], [17], [29], [41]], jnp.int32)
+    pos = jnp.asarray([3, 7, 12, 9], jnp.int32)       # wraps the window ring
+    active = np.asarray([True, False, True, False])
+
+    logits, every_row = jax.jit(tl.make_decode_step(cfg, program, None))(
+        params, cache, tok, pos)
+    want = jax.tree.map(
+        lambda new, old: jnp.where(
+            active.reshape((1, SLOTS) + (1,) * (new.ndim - 2)), new, old),
+        every_row, cache)
+    nxt, got = engine._decode(params, jax.tree.map(jnp.copy, cache), tok,
+                              pos, jnp.asarray(active))
+
+    for g, w, old in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                         jax.tree.leaves(cache)):
+        g, w, old = np.asarray(g), np.asarray(w), np.asarray(old)
+        assert np.array_equal(g[:, ~active], old[:, ~active])
+        assert np.array_equal(g[:, active], w[:, active])
+    assert not all(np.array_equal(np.asarray(a)[:, active],
+                                  np.asarray(b)[:, active])
+                   for a, b in zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(cache)))
+    assert np.array_equal(np.asarray(nxt)[active],
+                          np.asarray(jnp.argmax(logits[:, 0], -1))[active])
+
+
 def test_slot_reuse_after_retire():
     """More requests than slots: retired slots are re-leased and the
     reset rows carry no state from the previous tenant."""
