@@ -35,16 +35,17 @@ def _ints(s: str) -> list:
 def control_train(ctx) -> dict:
     from bench import compare, weights
     from bench.kinds.train import make_batches
-    m, tr, seed = ctx.model, ctx.traffic, ctx.seed
+    m, tr, seed, fam = ctx.model, ctx.traffic, ctx.seed, ctx.family
     wkey = weights.root_key(seed, "weights")
     batches = make_batches(m, tr, weights.root_key(seed, "data"))
     n = tr["check_steps"]
-    ref = compare.reference_training(m, tr, wkey, batches, n, keep_first=True)
+    ref = compare.reference_training(fam, m, tr, wkey, batches, n,
+                                     keep_first=True)
     out = {}
-    ctl = compare.reference_training(m, tr, wkey, batches, n, quant="fp8",
+    ctl = compare.reference_training(fam, m, tr, wkey, batches, n, quant="fp8",
                                      first=ref["first"])
     out["control_fp8"] = compare.training_readings(ctl, ref)
-    half = compare.reference_training(m, tr, wkey, batches, n,
+    half = compare.reference_training(fam, m, tr, wkey, batches, n,
                                       rows=tr["batch"] // 2, first=ref["first"])
     out["fault_half_batch"] = compare.training_readings(half, ref)
     out["reference_losses"] = ref["losses"]
@@ -63,8 +64,8 @@ def control_serve(ctx, kind) -> dict:
     from bench import compare, weights
     res = kind.run(ctx)
     wkey = weights.root_key(ctx.seed, "weights")
-    ref = compare.ServeReference(ctx.model, wkey)
-    low = compare.ServeReference(ctx.model, wkey, quant="fp8")
+    ref = compare.ServeReference(ctx.family, ctx.model, wkey)
+    low = compare.ServeReference(ctx.family, ctx.model, wkey, quant="fp8")
     return {"program": res["readings"],
             "control_fp8": compare.control_serve_readings(ref, low, res["sample"])}
 

@@ -27,24 +27,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights
-from bench.reference import dense
 from bench.reference.train import Trainer
 
 EXCLUDE_BELOW = 1e-3        # of the median leaf's reference gradient norm
-
-
-def check_config(cfg, m: dict) -> None:
-    """Raise unless the program's config is the one the file states."""
-    a = cfg.attention
-    got = {"n_layers": cfg.n_layers, "d_model": cfg.d_model, "d_ff": cfg.d_ff,
-           "vocab_size": cfg.vocab_size, "n_heads": a.n_heads,
-           "n_kv_heads": a.n_kv_heads, "head_dim": a.head_dim,
-           "qkv_bias": a.qkv_bias, "rope_theta": a.rope_theta,
-           "norm": cfg.norm, "act": cfg.act,
-           "tie_embeddings": cfg.tie_embeddings, "family": cfg.family}
-    want = {k: m[k] for k in got}
-    if got != want or a.window is not None:
-        raise RuntimeError(f"program config {got} is not the file's {want}")
 
 
 def judge(readings: dict, limits: dict) -> dict:
@@ -61,20 +46,21 @@ def _device_bytes() -> float:
     return float(stats.get("bytes_limit", 16e9))
 
 
-def reference_training(m: dict, traffic: dict, wkey, batches, n_steps: int,
-                       *, quant=None, rows=None, first=None,
+def reference_training(fam, m: dict, traffic: dict, wkey, batches,
+                       n_steps: int, *, quant=None, rows=None, first=None,
                        keep_first: bool = False) -> dict:
-    """The reference's first `n_steps` from the benchmark's weights and
-    batches: losses and per-leaf gradient and change norms in the
-    program's leaf names.  rows: use only the first `rows` of each batch
-    (a planted fault).  first: another run's first gradient (reference
-    layout, on the host), whose per-leaf distance from this run's is
-    returned as ``grad_diffs``; keep_first: return this run's first
-    gradient as ``first``."""
-    p_ref = jax.jit(lambda k: weights.to_reference(
-        m, weights.make_params(m, k)))(wkey)
+    """The first `n_steps` of the model family's (`fam`, bench/families/)
+    reference from the benchmark's weights and batches: losses and
+    per-leaf gradient and change norms in the program's leaf names.
+    rows: use only the first `rows` of each batch (a planted fault).
+    first: another run's first gradient (reference layout, on the host),
+    whose per-leaf distance from this run's is returned as
+    ``grad_diffs``; keep_first: return this run's first gradient as
+    ``first``."""
+    p_ref = jax.jit(lambda k: fam.to_reference(
+        m, weights.make_params(fam, m, k)))(wkey)
     nbytes = sum(x.size * 4 for x in jax.tree.leaves(p_ref))
-    trainer = Trainer(m, traffic["optimizer"], p_ref, quant=quant,
+    trainer = Trainer(fam, m, traffic["optimizer"], p_ref, quant=quant,
                       row_block=1, moments_on_host=4 * nbytes > 0.75 * _device_bytes())
     del p_ref
     losses, out = [], {}
@@ -87,7 +73,7 @@ def reference_training(m: dict, traffic: dict, wkey, batches, n_steps: int,
             loss, out["grad_norms"], extra = trainer.step(
                 tok, lab, against=first, keep=keep_first)
             if first is not None:
-                out["grad_diffs"] = weights.program_norms(m, extra)
+                out["grad_diffs"] = weights.program_norms(fam, m, extra)
             elif keep_first:
                 out["first"] = extra
         else:
@@ -96,13 +82,13 @@ def reference_training(m: dict, traffic: dict, wkey, batches, n_steps: int,
 
     @jax.jit
     def change_sumsq(p, k):
-        p0 = weights.to_reference(m, weights.make_params(m, k))
+        p0 = fam.to_reference(m, weights.make_params(fam, m, k))
         return weights.reference_sumsq(jax.tree.map(jnp.subtract, p, p0))
 
     change = {k: float(v) for k, v in change_sumsq(trainer.params, wkey).items()}
-    out["grad_norms"] = weights.program_norms(m, out["grad_norms"])
+    out["grad_norms"] = weights.program_norms(fam, m, out["grad_norms"])
     return dict(out, losses=losses,
-                change_norms=weights.program_norms(m, change))
+                change_norms=weights.program_norms(fam, m, change))
 
 
 def _worst(prog: dict, ref: dict, leaves) -> tuple:
@@ -135,25 +121,26 @@ def training_readings(prog: dict, ref: dict) -> dict:
 
 
 class ServeReference:
-    """Reference logits of whole sequences, layer by layer, with lengths
-    padded to a multiple of `bucket` so that few shapes compile."""
+    """Reference logits of whole sequences of the model family `fam`,
+    layer by layer, with lengths padded to a multiple of `bucket` so that
+    few shapes compile."""
 
-    def __init__(self, m: dict, wkey, *, quant=None, bucket: int = 512):
-        self.m, self.bucket = m, bucket
-        self.params = jax.jit(lambda k: weights.to_reference(
-            m, weights.make_params(m, k)))(wkey)
-        self._layer = jax.jit(partial(dense.layer, m, quant=quant))
-        self._logits = jax.jit(lambda hp, x: dense.logits(m, hp, x, quant))
-        self.hp = dense.head_params(self.params)
+    def __init__(self, fam, m: dict, wkey, *, quant=None, bucket: int = 512):
+        self.fam, self.m, self.bucket = fam, m, bucket
+        self.params = jax.jit(lambda k: fam.to_reference(
+            m, weights.make_params(fam, m, k)))(wkey)
+        self._layer = jax.jit(partial(fam.layer, m, quant=quant))
+        self._logits = jax.jit(lambda hp, x: fam.logits(m, hp, x, quant))
+        self.hp = fam.head_params(self.params)
 
     def logits(self, tokens: np.ndarray, at: np.ndarray) -> jax.Array:
         n = len(tokens)
         pad = -(-n // self.bucket) * self.bucket
         tok = np.zeros(pad, np.int32)
         tok[:n] = tokens
-        x = self.params["embed"][jnp.asarray(tok)][None]
-        for lp in self.params["layers"]:
-            x = self._layer(lp, x)
+        x = self.fam.embed(self.m, self.params, jnp.asarray(tok))[None]
+        for i, lp in enumerate(self.params["layers"]):
+            x = self._layer(i, lp, x)
         npos = -(-len(at) // self.bucket) * self.bucket
         idx = np.zeros(npos, np.int32)
         idx[:len(at)] = at

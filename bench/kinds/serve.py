@@ -29,7 +29,7 @@ from functools import partial
 import jax
 import numpy as np
 
-from bench import compare, flops, trace_reduce, weights
+from bench import compare, trace_reduce, weights
 from bench.system import program_config
 from bench.traffic.openloop import schedule
 
@@ -44,16 +44,16 @@ def build_engine(ctx):
     from repro.runtime import train_loop as tl
     from repro.serving.engine import ServingEngine
 
-    m, tr = ctx.model, ctx.traffic
-    cfg = program_config(ctx.config)
+    m, tr, fam = ctx.model, ctx.traffic, ctx.family
+    cfg = program_config(fam, ctx.config)
     shape = ShapeConfig("bench", seq_len=tr["max_len"],
                         global_batch=tr["slots"], kind="decode")
     program = compile_program(cfg, shape,
                               MeshSpec(axis_sizes={"data": 1, "model": 1}))
-    weights.check_layout(m, jax.eval_shape(
+    weights.check_layout(fam, m, jax.eval_shape(
         lambda k: tl.model_module(cfg).init(k, cfg), jax.random.PRNGKey(0)))
     wkey = weights.root_key(ctx.seed, "weights")
-    params = jax.jit(partial(weights.make_params, m))(wkey)
+    params = jax.jit(partial(weights.make_params, fam, m))(wkey)
     eng = ServingEngine(cfg, program, params, n_slots=tr["slots"],
                         max_len=tr["max_len"], prefill_chunk=tr["prefill_chunk"],
                         kernel_backend=tr["kernel_backend"])
@@ -125,7 +125,7 @@ def _wrap(eng, calls):
 
 
 def run(ctx) -> dict:
-    m, tr, seed = ctx.model, ctx.traffic, ctx.seed
+    m, tr, seed, fam = ctx.model, ctx.traffic, ctx.seed, ctx.family
     V = m["vocab_size"]
     eng, wkey = build_engine(ctx)
     rec = Recorder()
@@ -187,13 +187,13 @@ def run(ctx) -> dict:
               "window_s": window_s, "engine_step_ms": step_ms}
     if tracer:
         record["chunk_flops"] = [
-            flops.prefill_chunk_flops(m, int(np.asarray(p)[0]),
-                                      tr["prefill_chunk"])
+            fam.prefill_chunk_flops(m, int(np.asarray(p)[0]),
+                                    tr["prefill_chunk"])
             for p in calls["chunk"]]
         record["decode_bytes"] = []
         for pos, active in calls["decode"]:
             pos, active = np.asarray(pos), np.asarray(active)
-            record["decode_bytes"].append(flops.decode_step_bytes(
+            record["decode_bytes"].append(fam.decode_step_bytes(
                 m, int(pos[active].sum()), int(active.sum())))
         record["trace"] = tracer.reduce()
         tracer.cleanup()
@@ -205,7 +205,7 @@ def run(ctx) -> dict:
 
     sample = pick_sample(finished, rec, tr["check"], seed)
     t_ref = time.monotonic()
-    ref = compare.ServeReference(m, wkey)
+    ref = compare.ServeReference(fam, m, wkey)
     readings = compare.serve_readings(ref, sample)
     print(f"reference {time.monotonic() - t_ref:.3f} s: compared "
           f"{readings['tokens_compared']} served tokens of {len(sample)} "

@@ -1,7 +1,8 @@
 """Roofline share of the engine's masked decode program (_decode), in %:
 the least bytes of every decode step in the traced window (bf16 weights
-once, K/V of the live positions of the active rows, bench/flops.py) over
-HBM bandwidth, over the device time of the decode program's executions."""
+once, K/V of the live positions of the active rows: the model family's
+``decode_step_bytes``, bench/families/) over HBM bandwidth, over the
+device time of the decode program's executions."""
 from bench import roofline
 from bench.peaks import peaks_for
 

@@ -1,6 +1,7 @@
 """Model FLOP utilization of the training step: PaLM FLOPs per token
-(bench/flops.py) times the traced window's tokens per second, over the
-chip's bf16 peak, in %.  Recomputation does not count."""
+(the model family's ``train_flops_per_token``, bench/families/) times the
+traced window's tokens per second, over the chip's bf16 peak, in %.
+Recomputation does not count."""
 from bench.peaks import peaks_for
 
 

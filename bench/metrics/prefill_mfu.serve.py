@@ -1,7 +1,8 @@
 """FLOP utilization of the engine's prefill chunk program (_chunk), in %:
-the chunks' model FLOPs in the traced window (bench/flops.py: matmuls,
-causal attention over live positions, the head on the last position)
-over the device time of the chunk program's executions, over peak."""
+the chunks' model FLOPs in the traced window (the model family's
+``prefill_chunk_flops``, bench/families/: matmuls, causal attention over
+live positions, the head on the last position) over the device time of
+the chunk program's executions, over peak."""
 from bench import roofline
 from bench.peaks import peaks_for
 

@@ -1,5 +1,5 @@
-"""Reference training steps: the dense reference's loss and gradients,
-layer by layer, and AdamW in float32.
+"""Reference training steps: a model family's reference loss and
+gradients, layer by layer, and AdamW in float32.
 
 The whole batch is never held at once: activations are kept per layer and
 block of rows, and each layer's gradient is formed from its own
@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import dense
-
 
 def adamw_leaf(p, g, m, v, t, *, lr, weight_decay, b1, b2, eps):
     m = b1 * m + (1 - b1) * g
@@ -31,29 +29,35 @@ def adamw_leaf(p, g, m, v, t, *, lr, weight_decay, b1, b2, eps):
 class Trainer:
     """AdamW steps of the reference on one device.
 
-    params: reference layout (bench/reference/dense.py), float32 arrays on
-    the device; they are updated in place of the caller's tree.
+    fam: the model family (bench/families/<family>.py), whose reference
+    pieces (embed, layer, head_params, nll_sum, aux_loss) make the loss.
+    params: the family's reference layout, float32 arrays on the device;
+    they are updated in place of the caller's tree.
     opt: {"lr", "weight_decay", "b1", "b2", "eps"}.
     """
 
-    def __init__(self, m: dict, opt: dict, params: dict, *, quant=None,
+    def __init__(self, fam, m: dict, opt: dict, params: dict, *, quant=None,
                  row_block: int = 1, moments_on_host: bool = False):
-        self.m, self.params, self.quant = m, params, quant
+        self.fam, self.m, self.params, self.quant = fam, m, params, quant
         self.row_block = row_block
         self.t = 0
         self.on_host = moments_on_host
         self.moments: dict = {}
-        lay = partial(dense.layer, m, quant=quant)
+        lay = partial(fam.layer, m, quant=quant)
         self._fwd = jax.jit(lay)
-        self._vjp = jax.jit(lambda lp, x, g: jax.vjp(lay, lp, x)[1](g))
+        self._vjp = jax.jit(lambda i, lp, x, g: jax.vjp(partial(lay, i), lp, x)[1](g))
+        self._aux = None
+        if hasattr(fam, "aux_loss"):
+            self._aux = jax.jit(jax.value_and_grad(
+                partial(fam.aux_loss, m, quant=quant), argnums=(1, 2)))
         self._adam = jax.jit(partial(adamw_leaf, **{k: opt[k] for k in (
             "lr", "weight_decay", "b1", "b2", "eps")}), donate_argnums=(0, 2, 3))
-        self._embed = jax.jit(lambda e, t: e[t])
+        self._embed = jax.jit(partial(fam.embed, m))
         self._sumsq = jax.jit(lambda t: jax.tree.map(
             lambda a: jnp.sum(jnp.square(a)), t))
 
         def head(hp, x, labels, n_tok):
-            return dense.nll_sum(m, hp, x, labels, quant) / n_tok
+            return fam.nll_sum(m, hp, x, labels, quant) / n_tok
 
         self._head = jax.jit(jax.value_and_grad(head, argnums=(0, 1)))
         self._scatter = jax.jit(
@@ -80,18 +84,19 @@ class Trainer:
         host) extra holds the per-leaf sums of squares of the difference,
         with `keep` the gradient tree itself, on the host."""
         self.t += 1
-        P, m = self.params, self.m
+        P = self.params
         B = tokens.shape[0]
         rb = min(self.row_block, B)
         blocks = [(tokens[i:i + rb], labels[i:i + rb]) for i in range(0, B, rb)]
         n_tok = jnp.float32(tokens.size)
+        top = {k: v for k, v in P.items() if k != "layers"}
         acts = []
         for tok, _ in blocks:
-            xs = [self._embed(P["embed"], tok)]
-            for lp in P["layers"]:
-                xs.append(self._fwd(lp, xs[-1]))
+            xs = [self._embed(top, tok)]
+            for i, lp in enumerate(P["layers"]):
+                xs.append(self._fwd(i, lp, xs[-1]))
             acts.append(xs)
-        hp = dense.head_params(P)
+        hp, unhead = jax.vjp(self.fam.head_params, top)
         loss = 0.0
         dhp = None
         dxs = []
@@ -115,9 +120,15 @@ class Trainer:
 
         for li in reversed(range(len(P["layers"]))):
             lp = P["layers"][li]
-            dlp = None
+            dlp = dx_aux = None
+            if self._aux is not None:       # over the whole batch at once
+                val, (dlp, dx_aux) = self._aux(
+                    li, lp, jnp.concatenate([xs[-1] for xs in acts]))
+                loss += float(val)
             for r, xs in enumerate(acts):
-                g_lp, dxs[r] = self._vjp(lp, xs.pop(), dxs[r])
+                g_lp, dxs[r] = self._vjp(li, lp, xs.pop(), dxs[r])
+                if dx_aux is not None:
+                    dxs[r] = dxs[r] + dx_aux[r * rb:(r + 1) * rb]
                 dlp = g_lp if dlp is None else jax.tree.map(jnp.add, dlp, g_lp)
             add("layers.", dlp, against and against["layers"][li])
             if keep:
@@ -127,12 +138,9 @@ class Trainer:
         d_embed = jnp.zeros_like(P["embed"])
         for (tok, _), dx in zip(blocks, dxs):
             d_embed = self._scatter(d_embed, tok, dx)
-        top = {"embed": d_embed if "lm_head" in P else d_embed + dhp["head"].T}
-        if "lm_head" in P:
-            top["lm_head"] = dhp["head"]
-        if "final_norm" in P:
-            top["final_norm"] = dhp["final_norm"]
-        for k, g in top.items():
+        (g_top,) = unhead(dhp)              # the head's gradient on the leaves
+        g_top["embed"] = d_embed + g_top["embed"]
+        for k, g in g_top.items():
             add("", {k: g}, against and {k: against[k]})
             if keep:
                 kept[k] = jax.device_get(g)

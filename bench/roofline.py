@@ -1,33 +1,39 @@
 """Roofline and device-time arithmetic shared by the per-layer readers."""
 from __future__ import annotations
 
+import glob
+import os
+
 from bench import flops
 from bench.peaks import peaks_for
+from bench.run import load_module
 
-KERNELS = ("sr_matmul", "outer_accum")
+KERNELS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "kernels")
 
 
-def call_cost(kernel: str, shapes: list) -> tuple:
-    """(FLOPs, bytes) of one kernel call from its HLO shapes, result
-    first: sr_matmul (M, N) <- (M, K) x (K, N) or (N, K); outer_accum
-    (D, F) <- (T, D) x (T, F)."""
-    (_, out), (_, a) = shapes[0], shapes[1]
-    if kernel == "sr_matmul":
-        return flops.matmul_cost(out[0], a[1], out[1])
-    if kernel == "outer_accum":
-        return flops.outer_cost(a[0], out[0], out[1])
-    raise KeyError(kernel)
+def kernels(directory: str | None = None) -> dict:
+    """{name: module} of every Pallas kernel file, bench/kernels/<name>.py:
+    ``match(shapes)`` tells its calls apart, ``cost(shapes)`` counts one."""
+    out = {}
+    for path in sorted(glob.glob(os.path.join(directory or KERNELS_DIR, "*.py"))):
+        name = os.path.basename(path)[:-3]
+        out[name] = load_module(path, f"bench_kernel_{name}")
+    return out
+
+
+def _calls(rec: dict, kernel: str) -> list:
+    return [k for k in (rec.get("trace") or {}).get("kernels", [])
+            if k["kernel"] == kernel]
 
 
 def share(rec: dict, kernel: str):
     """Sum of least times over sum of traced durations of `kernel`, in %;
     None where the trace holds no such call."""
-    t = rec.get("trace")
-    calls = [k for k in (t or {}).get("kernels", []) if k["kernel"] == kernel]
+    calls = _calls(rec, kernel)
     if not calls:
         return None
-    least = sum(flops.least_seconds(*call_cost(kernel, k["shapes"]),
-                                    peaks_for(rec["device_kind"]))[0] for k in calls)
+    cost, peaks = kernels()[kernel].cost, peaks_for(rec["device_kind"])
+    least = sum(flops.least_seconds(*cost(k["shapes"]), peaks)[0] for k in calls)
     spent = sum(k["seconds"] for k in calls)
     return 100.0 * least / spent if spent > 0 else None
 
@@ -35,11 +41,10 @@ def share(rec: dict, kernel: str):
 def bound_by(rec: dict, kernel: str) -> dict:
     """{compute|memory: calls} of `kernel` in the trace."""
     out: dict = {}
-    for k in rec.get("trace", {}).get("kernels", []):
-        if k["kernel"] == kernel:
-            b = flops.least_seconds(*call_cost(kernel, k["shapes"]),
-                                    peaks_for(rec["device_kind"]))[1]
-            out[b] = out.get(b, 0) + 1
+    cost, peaks = kernels()[kernel].cost, peaks_for(rec["device_kind"])
+    for k in _calls(rec, kernel):
+        b = flops.least_seconds(*cost(k["shapes"]), peaks)[1]
+        out[b] = out.get(b, 0) + 1
     return out
 
 

@@ -6,9 +6,12 @@ A cell of BENCHMARK.json names a configuration (bench/configs/<name>.json)
 and a traffic mix (bench/traffic/<name>.json); the mix's ``kind`` picks
 the module (bench/kinds/<kind>.py), which sets up, measures for
 ``--seconds`` and checks the timed path's output against the plain
-reference (bench/reference/).  The limits of that check are in
+reference (bench/reference/).  The configuration's model ``family`` picks
+the module that knows the model's layout, reference and counts
+(bench/families/<family>.py).  The limits of that check are in
 bench/limits/<cell>.json.  With ``--trace 1`` the window is profiled
-and each per-layer metric of the cell is read by bench/metrics/<name>.py.
+and each per-layer metric of the cell is read by bench/metrics/<name>.py;
+the Pallas kernels are told apart and counted by bench/kernels/<name>.py.
 
 The last line of stdout is one JSON object (correct, attempted, failed,
 metrics, device[, breakdown, pallas_unmatched], checks).  The numbers
@@ -32,6 +35,7 @@ from dataclasses import dataclass, field  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
+FAMILIES = os.path.join(BENCH, "families")
 
 
 class Refused(RuntimeError):
@@ -50,6 +54,10 @@ class Ctx:
     trace: bool
     t_start: float
     compiles: list = field(default_factory=list)
+    family: object = field(init=False)     # bench/families/<model family>.py
+
+    def __post_init__(self):
+        self.family = load_family(self.model["family"])
 
     @property
     def model(self) -> dict:
@@ -66,6 +74,14 @@ def load_module(path: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(name: str):
+    """The module of a model family (bench/families/<name>.py)."""
+    path = os.path.join(FAMILIES, f"{name}.py")
+    if not os.path.isfile(path):
+        raise Refused(f"unknown model family {name!r}: no file {path}")
+    return load_module(path, f"bench_family_{name}")
 
 
 def cell_files(bench: dict, workload: str) -> tuple:
@@ -140,9 +156,9 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     if os.path.join(ROOT, "src") not in sys.path:
         sys.path.insert(0, os.path.join(ROOT, "src"))
     cell, config, traffic, limits = files or cell_files(bench, workload)
+    ctx = Ctx(cell, config, traffic, limits, seed, seconds, trace, T_START)
     enable_compile_cache()
     dev = device_info(require_tpu, cell["chips"])
-    ctx = Ctx(cell, config, traffic, limits, seed, seconds, trace, T_START)
     count_compiles(ctx)
     kind = load_module(os.path.join(BENCH, "kinds", f"{traffic['kind']}.py"),
                        f"bench_kind_{traffic['kind']}")

@@ -12,13 +12,11 @@ def _replace(obj, overrides: dict):
     return dataclasses.replace(obj, **kw)
 
 
-def program_config(config: dict):
+def program_config(fam, config: dict):
     """The registry's config for ``config["arch"]`` with ``overrides``
-    applied (nested dataclasses take nested dicts), checked against the
-    file's ``model`` block."""
+    applied (nested dataclasses take nested dicts), checked by the model
+    family `fam` against the file's ``model`` block."""
     from repro.configs import get_config
-
-    from bench.compare import check_config
     cfg = _replace(get_config(config["arch"]), config["overrides"])
-    check_config(cfg, config["model"])
+    fam.check_config(cfg, config["model"])
     return cfg

@@ -3,9 +3,11 @@ program's place, has to come out as not correct.  At the small size on
 the CPU; on the chip at the cell's size it is run by bench/calibrate.py."""
 import pytest
 
-from bench import compare, weights
+from bench import compare, run, weights
 from bench.kinds.train import make_batches
 from bench.tests import small
+
+FAM = run.load_family("dense")
 
 
 @pytest.mark.parametrize("arch", ["qwen2-0.5b", "olmo-1b"])
@@ -14,9 +16,9 @@ def test_train_control_fails(arch, seed):
     m, tr = small.config(arch)["model"], small.TRAIN
     wkey = weights.root_key(seed, "weights")
     batches = make_batches(m, tr, weights.root_key(seed, "data"))
-    ref = compare.reference_training(m, tr, wkey, batches, tr["check_steps"],
+    ref = compare.reference_training(FAM, m, tr, wkey, batches, tr["check_steps"],
                                      keep_first=True)
-    ctl = compare.reference_training(m, tr, wkey, batches, tr["check_steps"],
+    ctl = compare.reference_training(FAM, m, tr, wkey, batches, tr["check_steps"],
                                      quant="fp8", first=ref["first"])
     checks = compare.judge(compare.training_readings(ctl, ref),
                            {"limits": small.TRAIN_LIMITS})
@@ -29,8 +31,8 @@ def test_serve_control_fails():
     import numpy as np
     m = small.config("qwen2-0.5b")["model"]
     wkey = weights.root_key(4, "weights")
-    ref = compare.ServeReference(m, wkey, bucket=32)
-    low = compare.ServeReference(m, wkey, quant="fp8", bucket=32)
+    ref = compare.ServeReference(FAM, m, wkey, bucket=32)
+    low = compare.ServeReference(FAM, m, wkey, quant="fp8", bucket=32)
     rng = np.random.default_rng(0)
     sample = [(rng.integers(0, m["vocab_size"], n), rng.integers(0, m["vocab_size"], 20))
               for n in (10, 40)]

@@ -4,7 +4,7 @@ import os
 
 import pytest
 
-from bench import flops, roofline, run
+from bench import roofline, run
 
 METRICS = os.path.join(os.path.dirname(os.path.dirname(__file__)), "metrics")
 KIND = "TPU v5 lite"
@@ -43,7 +43,8 @@ def test_kernel_roofline_from_shapes():
 
 def test_outer_accum_cost():
     shapes = [("bf16", (896, 1152)), ("bf16", (16384, 896)), ("bf16", (16384, 1152))]
-    assert roofline.call_cost("outer_accum", shapes) == flops.outer_cost(16384, 896, 1152)
+    outer = roofline.kernels()["outer_accum"]
+    assert outer.cost(shapes) == outer.outer_cost(16384, 896, 1152)
 
 
 def test_serving_readers():

@@ -5,13 +5,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import weights
+from bench import run, weights
 from bench.reference import dense
 from bench.reference.train import Trainer, adamw_leaf
 from bench.system import program_config
 from bench.tests import small
 
 OPT = small.TRAIN["optimizer"]
+FAM = run.load_family("dense")
 
 
 def program_loss(cfg, params, tokens, labels):
@@ -37,14 +38,14 @@ def batch(m, key, b=2, t=24):
 def test_loss_and_gradients_match_program(arch):
     c = small.config(arch)
     m = c["model"]
-    cfg = program_config(c)
-    p = weights.make_params(m, weights.root_key(3, "weights"), jnp.float32)
+    cfg = program_config(FAM, c)
+    p = weights.make_params(FAM, m, weights.root_key(3, "weights"), jnp.float32)
     tok, lab = batch(m, jax.random.PRNGKey(1))
     lp, gp = jax.value_and_grad(program_loss, argnums=1)(cfg, p, tok, lab)
-    ref = weights.to_reference(m, p)
+    ref = FAM.to_reference(m, p)
     lr, gr = jax.value_and_grad(lambda q: dense.loss(m, q, tok, lab))(ref)
     np.testing.assert_allclose(float(lr), float(lp), rtol=1e-5)
-    want = weights.program_norms(m, weights.reference_sumsq(gr))
+    want = weights.program_norms(FAM, m, weights.reference_sumsq(gr))
     got = {k: float(jnp.linalg.norm(v)) for k, v in weights.flatten(gp).items()}
     assert set(got) == set(want)
     for k in want:
@@ -58,9 +59,9 @@ def test_trainer_matches_whole_model_adamw(on_host):
     c = small.config("qwen2-0.5b")
     m = c["model"]
     key = weights.root_key(5, "weights")
-    make = lambda: weights.to_reference(m, weights.make_params(m, key))
+    make = lambda: FAM.to_reference(m, weights.make_params(FAM, m, key))
     direct = make()
-    tr = Trainer(m, OPT, make(), row_block=1, moments_on_host=on_host)
+    tr = Trainer(FAM, m, OPT, make(), row_block=1, moments_on_host=on_host)
     mom = jax.tree.map(lambda a: (jnp.zeros_like(a), jnp.zeros_like(a)), direct)
     for s in range(2):
         tok, lab = batch(m, jax.random.PRNGKey(10 + s), b=3)

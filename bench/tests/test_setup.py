@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from bench import weights
+from bench import run, weights
 from bench.kinds.train import batch_rows
 from bench.tests import small
 
@@ -23,6 +23,6 @@ def test_batches_program_is_seed_free():
 
 def test_weights_program_is_seed_free():
     m = small.config("olmo-1b")["model"]
-    make = jax.jit(partial(weights.make_params, m))
+    make = jax.jit(partial(weights.make_params, run.load_family("dense"), m))
     assert _hlo(make, weights.root_key(3, "weights")) == _hlo(
         make, weights.root_key(2**33 + 7, "weights"))

@@ -2,6 +2,7 @@
 recorded on a TPU v5e (bench/tests/record_trace.py) with its numbers
 worked out by hand from the events listed below."""
 import os
+import shutil
 
 import pytest
 
@@ -54,6 +55,7 @@ def _call(result, *operands):
     ("sr_matmul", (512, 384), (512, 256), (256, 384)),      # FF
     ("sr_matmul", (512, 256), (512, 384), (256, 384)),      # BP, trans_b
     ("outer_accum", (256, 384), (2048, 256), (2048, 384)),  # UP
+    ("sr_matmul", (512, 256), (512, 512), (512, 256)),      # FF or UP: T = K
     (None, (512, 384), (384, 256), (256, 512)),             # fits neither
 ])
 def test_kernel_told_by_shapes_with_or_without_entropy(kernel, result, a, b, bits):
@@ -127,3 +129,37 @@ def test_recorded_trace_roofline(small):
     # outer_accum: X (256 x 512), dY (256 x 384): the same counts, 2.411 us
     assert roofline.share(rec, "outer_accum") == pytest.approx(
         100 * 851968 / 819e9 / 2411e-9)
+
+
+def test_a_kernel_is_a_file(tmp_path):
+    """A Pallas call is the kernel whose file claims it: a new file adds a
+    kernel ((A B)^T here, a call the two kernels do not claim), and a call
+    that two files claim stops the reduction."""
+    from bench import roofline
+    for name in ("sr_matmul", "outer_accum"):
+        shutil.copy(os.path.join(roofline.KERNELS_DIR, f"{name}.py"), tmp_path)
+    (tmp_path / "swapped.py").write_text(
+        "def match(shapes):\n"
+        "    (_, out), (_, a), (_, b) = shapes[:3]\n"
+        "    return a[1] == b[0] and out == (b[1], a[0])\n\n\n"
+        "def cost(shapes):\n"
+        "    return 0.0, 0.0\n")
+    files = roofline.kernels(str(tmp_path))
+    shapes = [("f32", (512, 384)), ("bf16", (384, 256)), ("bf16", (256, 512))]
+    assert tr.classify(shapes, files) == "swapped"
+    assert tr.classify(shapes) is None
+    shutil.copy(tmp_path / "sr_matmul.py", tmp_path / "sr_matmul_again.py")
+    files = roofline.kernels(str(tmp_path))
+    with pytest.raises(RuntimeError, match=r"\['sr_matmul', 'sr_matmul_again'\]"):
+        tr.classify([("f32", (512, 384)), ("bf16", (512, 256)),
+                     ("bf16", (256, 384))], files)
+
+
+def test_recorded_trace_with_a_kernel_claimed_twice(tmp_path, monkeypatch):
+    from bench import roofline
+    for name in ("sr_matmul", "outer_accum"):
+        shutil.copy(os.path.join(roofline.KERNELS_DIR, f"{name}.py"), tmp_path)
+    shutil.copy(tmp_path / "outer_accum.py", tmp_path / "outer_accum_again.py")
+    monkeypatch.setattr(roofline, "KERNELS_DIR", str(tmp_path))
+    with pytest.raises(RuntimeError, match="outer_accum_again"):
+        tr.reduce_file(DATA)

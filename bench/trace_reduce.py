@@ -17,13 +17,15 @@ program comes from the "XLA Modules" line.
 
 The Pallas kernels are the ``tpu_custom_call`` ops.  Their instruction
 names come from the caller's jit, not from the kernel, so each call is
-told apart by its result and first two operand shapes (``classify``):
-``sr_matmul`` computes (M, N) from (M, K) and (K, N), or (N, K) with
-``trans_b``; ``outer_accum`` computes (D, F) from (T, D) and (T, F).
-Further operands, such as the uint32 entropy array of the result's shape
-that both take today, are not read, so a kernel without it is still
-recognised.  A Pallas call that fits neither is counted, with its device
-time, under ``pallas_unmatched``.
+told apart by its result and operand shapes (``classify``): every kernel
+file, bench/kernels/<kernel>.py, says by ``match`` whether a call is its
+own, and a call that two files claim is an error unless one yields it to
+the other (``YIELDS_TO``).  ``sr_matmul`` computes
+(M, N) from (M, K) and (K, N), or (N, K) with ``trans_b``; ``outer_accum``
+computes (D, F) from (T, D) and (T, F).  Both read only the result and
+the first two operands, so a kernel without the uint32 entropy operand
+that both take today is still recognised.  A Pallas call that fits no
+kernel is counted, with its device time, under ``pallas_unmatched``.
 """
 from __future__ import annotations
 
@@ -35,6 +37,8 @@ import tempfile
 
 import jax
 import numpy as np
+
+from bench import roofline
 
 WINDOW = "window"
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
@@ -169,28 +173,29 @@ def hlo_shapes(text: str) -> list:
             for d, s in _SHAPE.findall(text)]
 
 
-def classify(shapes: list):
+def classify(shapes: list, kernels: dict | None = None):
     """The kernel of a Pallas call from its [result, a, b, ...] shapes, or
-    None when they fit neither."""
-    if len(shapes) < 3:
-        return None
-    (_, out), (_, a), (_, b) = shapes[:3]
-    if len(out) != 2 or len(a) != 2 or len(b) != 2:
-        return None
-    if a[0] == out[0] and (b == (a[1], out[1]) or b == (out[1], a[1])):
-        return "sr_matmul"
-    if a[0] == b[0] and (a[1], b[1]) == out:
-        return "outer_accum"
-    return None
+    None when they fit no kernel; a file that claims the call yields it
+    to a claimant named in its ``YIELDS_TO``.  kernels: {name: module} as
+    ``roofline.kernels`` loads them (the default)."""
+    kernels = roofline.kernels() if kernels is None else kernels
+    hits = [name for name, k in kernels.items() if k.match(shapes)]
+    hits = [h for h in hits
+            if not set(getattr(kernels[h], "YIELDS_TO", ())) & set(hits)]
+    if len(hits) > 1:
+        raise RuntimeError(f"a Pallas call of shapes {shapes} fits more than "
+                           f"one kernel file: {hits}")
+    return hits[0] if hits else None
 
 
-def kernel_event(text: str, seconds: float):
+def kernel_event(text: str, seconds: float, kernels: dict | None = None):
     """{kernel, seconds, shapes} for a Pallas op (kernel None where its
-    shapes fit neither kernel), else None."""
+    shapes fit no kernel), else None."""
     if PALLAS_TARGET not in text:
         return None
     shapes = hlo_shapes(text.split(PALLAS_TARGET, 1)[0])
-    return {"kernel": classify(shapes), "seconds": seconds, "shapes": shapes[:3]}
+    return {"kernel": classify(shapes, kernels), "seconds": seconds,
+            "shapes": shapes[:3]}
 
 
 def _planes(pd):
@@ -229,6 +234,7 @@ def reduce_profile(pd) -> dict:
     if not device:
         raise RuntimeError("no device plane with an 'XLA Ops' line")
     lo, hi = window
+    files = roofline.kernels()
     busy_ns, ops, modules, kernels, idle = [], {}, {}, [], []
     unmatched = {"calls": 0, "seconds": 0.0}
     for plane in device:
@@ -247,7 +253,7 @@ def reduce_profile(pd) -> dict:
                     continue
                 names.append(e.name)
                 iv.append((s, t))
-                k = kernel_event(e.name, e.duration_ns * 1e-9)
+                k = kernel_event(e.name, e.duration_ns * 1e-9, files)
                 if k is not None and k["kernel"] is None:
                     unmatched["calls"] += 1
                     unmatched["seconds"] += k["seconds"]
